@@ -9,11 +9,12 @@
  * Best-SWL rotation) are serviced HERE, in-stepper, as transliterations
  * of the repro.core.epoch kernels; a cell pauses back into Python only
  * for unknown policy subclasses (F_OBJECT / WD_OBJECT rows) and for
- * row finalization. Decision floats follow the fixed-point contract of
- * repro/core/epoch.py: integer counters below 2**53, each cutoff
- * decision a single-rounding double compare (hits*act <> cutoff*win),
- * so numpy and C agree bit-for-bit (tests/test_batched.py). Compile
- * with -ffp-contract=off so no compare side is fused.
+ * row finalization. Decisions follow the fixed-point contract of
+ * repro/core/epoch.py: integer counters, and each cutoff or threshold
+ * decision an int64 compare against a (num, den) rational
+ * (hits*act*den <> num*win), so numpy and C agree bit-for-bit
+ * (tests/test_batched.py). The timeline's IPC samples are the one float
+ * result; compile with -ffp-contract=off so nothing is fused.
  *
  * Compiled on demand by repro/core/_cstep.py with the system C compiler
  * (no Python.h — driven through ctypes). Field order of Params must
@@ -86,11 +87,11 @@ typedef struct {
     /* ---- in-stepper epoch / warp-done / timeline servicing ---- */
     i64 timeline_every, tl_cap;
     i64 *high_epoch, *aging_high, *stride_ok;   /* per-row knobs */
-    double *low_cutoff, *high_cutoff;
+    i64 *low_cutoff, *high_cutoff;      /* B x 2 (num, den) */
     i8 *fam, *mode_p, *mode_t;          /* policy family / CIAO modes */
     i8 *allowed_pl, *isolated_pl, *bypass_pl;   /* policy mask planes */
     i8 *sp_bypass, *sp_base;            /* statPCAL mode + base set */
-    double *sp_thresh;
+    i64 *sp_thresh;                     /* B x 2 (num, den) */
     i64 *det_inst_total, *det_irs_inst, *irs_off;
     i64 *low_idx, *high_idx, *low_base_inst, *high_base_inst;
     i64 *high_crossings, *low_base_hits, *high_base_hits;
@@ -266,22 +267,20 @@ static void ccws_tick_row(const Params *p, i64 b)
     }
 }
 
-/* statPCAL: bandwidth-driven bypass flip (epoch.statpcal_tick); util
- * is the single-rounding double of BatchedSMEngine._util_vec */
+/* statPCAL: bandwidth-driven bypass flip (epoch.statpcal_tick); the
+ * integer underutilization compare of epoch.util_below */
 static void statp_tick_row(const Params *p, i64 b, i64 cycle)
 {
     const i64 n = p->n;
-    double util = 0.0;
+    const i64 num = p->sp_thresh[2 * b], den = p->sp_thresh[2 * b + 1];
+    int nb = num > 0;
     if (cycle > 0) {
-        i64 den = p->dram_channels * cycle;
-        if (den < 1)
-            den = 1;
-        util = (double)(p->dram_requests[p->mem_of[b]] * p->dram_gap[b])
-            / (double)den;
-        if (util > 1.0)
-            util = 1.0;
+        i64 cc = p->dram_channels * cycle;
+        i64 busy = p->dram_requests[p->mem_of[b]] * p->dram_gap[b];
+        if (busy > cc)
+            busy = cc;
+        nb = busy * den < num * cc;
     }
-    int nb = util < p->sp_thresh[b];
     if (nb == (int)p->sp_bypass[b])
         return;
     p->sp_bypass[b] = (i8)nb;
@@ -306,7 +305,8 @@ static int ciao_pop_ok(const Params *p, i64 b, i64 k, i64 act,
         return 1;
     const i64 *ih = (const i64 *)(uintptr_t)p->det_ptrs[b * 4 + 0];
     i64 hits = ih[k % p->nw];
-    return (double)(hits * act) <= p->low_cutoff[b] * (double)inst;
+    return hits * act * p->low_cutoff[2 * b + 1]
+        <= p->low_cutoff[2 * b] * inst;
 }
 
 /* epoch-crossing poll + windowed IRS snapshots + aging
@@ -429,7 +429,8 @@ static void ciao_high_row(const Params *p, i64 b)
     for (i64 r = 0; r < na; r++) {
         i64 i = scored[r];
         i64 h = hits[i % nw];
-        if (!((double)(h * act) > p->high_cutoff[b] * (double)win))
+        if (!(h * act * p->high_cutoff[2 * b + 1]
+              > p->high_cutoff[2 * b] * win))
             break; /* sorted descending: nothing further exceeds */
         i64 j = interf[i % le];
         if (j == -1 || j == i || done[j])

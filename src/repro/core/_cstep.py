@@ -88,12 +88,12 @@ class Params(ctypes.Structure):
         ("timeline_every", _c_i64), ("tl_cap", _c_i64),
         ("high_epoch", _p_i64), ("aging_high", _p_i64),
         ("stride_ok", _p_i64),
-        ("low_cutoff", _p_f64), ("high_cutoff", _p_f64),
+        ("low_cutoff", _p_i64), ("high_cutoff", _p_i64),
         ("fam", _p_i8), ("mode_p", _p_i8), ("mode_t", _p_i8),
         ("allowed_pl", _p_i8), ("isolated_pl", _p_i8),
         ("bypass_pl", _p_i8),
         ("sp_bypass", _p_i8), ("sp_base", _p_i8),
-        ("sp_thresh", _p_f64),
+        ("sp_thresh", _p_i64),
         ("det_inst_total", _p_i64), ("det_irs_inst", _p_i64),
         ("irs_off", _p_i64),
         ("low_idx", _p_i64), ("high_idx", _p_i64),
@@ -269,15 +269,15 @@ def bind(eng, det_ptrs, score_ptrs, bumps) -> Params:
     p.stride_ok = _i64(stride_i64)
     p.timeline_every = eng.timeline_every
     p.tl_cap = eng.tl_cap
-    p.low_cutoff = _f64(eng.det_pl.low_cutoff)
-    p.high_cutoff = _f64(eng.det_pl.high_cutoff)
+    p.low_cutoff = _i64(eng.det_pl.low_cutoff)
+    p.high_cutoff = _i64(eng.det_pl.high_cutoff)
     p.fam = _i8(eng.fam)
     p.mode_p, p.mode_t = _i8(eng.mode_p), _i8(eng.mode_t)
     p.allowed_pl = _i8(eng.allowed_pl)
     p.isolated_pl = _i8(eng.isolated_pl)
     p.bypass_pl = _i8(eng.bypass_pl)
     p.sp_bypass, p.sp_base = _i8(eng.sp_bypass), _i8(eng.sp_base)
-    p.sp_thresh = _f64(eng.sp_thresh)
+    p.sp_thresh = _i64(eng.sp_thresh)
     pl = eng.det_pl
     p.det_inst_total = _i64(pl.inst_total)
     p.det_irs_inst = _i64(pl.irs_inst)

@@ -426,7 +426,7 @@ class BatchedSMEngine:
         self.ccws_base = np.zeros(B, i64)
         self.ccws_budget = np.zeros(B, i64)
         self.sp_bypass = np.zeros(B, b8)
-        self.sp_thresh = np.zeros(B, np.float64)
+        self.sp_thresh = np.zeros((B, 2), np.int64)     # (num, den)
         self.sp_base = np.zeros((B, n), b8)
         self.ciao_stall = np.full((B, n), -1, i64)
         self.ciao_iso = np.full((B, n), -1, i64)
@@ -583,24 +583,17 @@ class BatchedSMEngine:
         if self.policies[b].mask_version != self.mask_ver[b]:
             self._refresh_masks(b)
 
-    def _util(self, b: int) -> float:
-        cyc = int(self.cycle[b])
-        if cyc <= 0:
-            return 0.0
-        util = int(self.dram_requests[self.mem_of[b]]) \
-            * int(self.dram_gap[b]) / (self.dram_channels * cyc)
-        return 1.0 if util > 1.0 else util
+    def _load(self, b: int) -> Tuple[int, int]:
+        return (int(self.dram_requests[self.mem_of[b]]) * int(self.dram_gap[b]),
+                self.dram_channels * int(self.cycle[b]))
 
-    def _util_vec(self, idx: np.ndarray) -> np.ndarray:
-        """statPCAL's DRAM utilization, per flagged row (chip-wide
-        request count over the row's local cycle — exactly the scalar
-        fused path's formula)."""
-        cyc = self.cycle[idx]
-        reqs = self.dram_requests[self.mem_of[idx]]
-        util = np.where(cyc > 0,
-                        reqs * self.dram_gap[idx]
-                        / np.maximum(self.dram_channels * cyc, 1), 0.0)
-        return np.minimum(util, 1.0)
+    def _util_below(self, idx: np.ndarray) -> np.ndarray:
+        """statPCAL's underutilization decision per flagged row: the
+        chip-wide request count over the row's local cycle (the scalar
+        fused path's formula) against the row's threshold."""
+        return _epoch.util_below(
+            self.dram_requests[self.mem_of[idx]] * self.dram_gap[idx],
+            self.dram_channels * self.cycle[idx], self.sp_thresh[idx])
 
     def _epoch_batch(self, idx: np.ndarray, anchor: np.ndarray) -> None:
         """Service the epoch boundary for every row in ``idx`` with ONE
@@ -625,9 +618,9 @@ class BatchedSMEngine:
         sel = fam == F_STATP
         if sel.any():
             s = idx[sel]
-            _epoch.statpcal_tick(self.sp_bypass, self._util_vec(s),
-                                 self.sp_thresh, self.sp_base,
-                                 self.allowed_pl, self.bypass_pl, s)
+            _epoch.statpcal_tick(self.sp_bypass, self._util_below(s),
+                                 self.sp_base, self.allowed_pl,
+                                 self.bypass_pl, s)
         sel = fam == F_CIAO
         if sel.any():
             g = idx[sel]
@@ -675,7 +668,7 @@ class BatchedSMEngine:
         the scalar loop."""
         pol = self.policies[b]
         pol.epoch_tick(None, self.done[b, :int(self.n_of[b])],
-                       self._util(b))
+                       self._load(b))
         self._maybe_refresh(b)
 
     def _warp_done_rows(self, rows: np.ndarray, wids: np.ndarray) -> None:

@@ -36,17 +36,23 @@ Bit-exactness notes: every arithmetic step mirrors the scalar semantics
 elementwise — int64 floor divisions and stable sorts wherever the scalar
 code relied on Python's stable ``sorted``/``argsort``. The IRS state is
 **fixed-point**: snapshots are stored as the integer triple
-``(hits, window, active)`` and every cutoff decision is the
-single-rounding float64 compare ``hits*active <> cutoff*window``. All
-integer operands stay far below 2**53, so the int64->float64
-conversions are exact, the compare performs exactly one IEEE rounding
-per side, and the decision is bit-deterministic across numpy, the C
-stepper, and XLA — no accumulated float state ever crosses an epoch
+``(hits, window, active)`` and every cutoff decision is exact integer
+arithmetic: a cutoff knob stands for the rational ``num/den`` nearest
+to it with ``den <= CUTOFF_DEN_MAX`` (:func:`ratio` — exact for decimals
+of up to six places and for dyadics down to 2**-19), and the IRS
+compare ``hits/(window/active) <> num/den`` is evaluated as the int64
+compare ``hits*active*den <> num*window``. Instruction counts stay below
+2**32, active warps below 2**8 and ``den < 2**20``, so no product comes
+near 2**63. No float enters a decision, so it is
+bit-deterministic across numpy, the C stepper, and XLA — also on a chip
+that has no IEEE double (TPU v5e emulates float64 with a pair of
+float32) — and no accumulated float state ever crosses an epoch
 boundary.
 """
 from __future__ import annotations
 
 import dataclasses
+from fractions import Fraction
 from typing import Tuple
 
 import numpy as np
@@ -57,6 +63,15 @@ _DEAD_KEY = np.iinfo(np.int64).max
 
 # reusable batch-of-1 index (the scalar objects' delegation path)
 IDX0 = np.zeros(1, np.int64)
+
+CUTOFF_DEN_MAX = 10**6
+
+
+def ratio(x: float) -> Tuple[int, int]:
+    """The rational ``(num, den)`` a cutoff or threshold knob stands for:
+    the nearest one with ``den <= CUTOFF_DEN_MAX``."""
+    f = Fraction(x).limit_denominator(CUTOFF_DEN_MAX)
+    return f.numerator, f.denominator
 
 
 # --------------------------------------------------------------- planes
@@ -97,8 +112,8 @@ class DetPlanes:
     low_epoch: np.ndarray            # (B,) i64
     high_epoch: np.ndarray           # (B,) i64
     aging_high: np.ndarray           # (B,) i64  0 disables aging
-    low_cutoff: np.ndarray           # (B,) f64
-    high_cutoff: np.ndarray          # (B,) f64
+    low_cutoff: np.ndarray           # (B, 2) i64 (num, den), see ratio
+    high_cutoff: np.ndarray          # (B, 2) i64
     wid_sets: np.ndarray             # (nw,) i64  wid -> vta set index
 
     @classmethod
@@ -130,8 +145,9 @@ class DetPlanes:
             low_epoch=np.full(b, cfg.low_epoch, i64),
             high_epoch=np.full(b, cfg.high_epoch, i64),
             aging_high=np.full(b, cfg.aging_high_epochs, i64),
-            low_cutoff=np.full(b, cfg.low_cutoff, np.float64),
-            high_cutoff=np.full(b, cfg.high_cutoff, np.float64),
+            low_cutoff=np.tile(np.array(ratio(cfg.low_cutoff), i64), (b, 1)),
+            high_cutoff=np.tile(np.array(ratio(cfg.high_cutoff), i64),
+                                (b, 1)),
             wid_sets=np.arange(nw, dtype=i64) % cfg.vta_sets,
         )
 
@@ -209,7 +225,7 @@ def irs_cumulative(pl: DetPlanes, idx: np.ndarray, wid: np.ndarray,
     """Eq. 1 over the aged cumulative counters, vectorized:
     ``irs_hits[wid] * active / irs_inst`` with the scalar guards
     (zero denominator -> 0.0). Reporting only — cutoff *decisions* go
-    through :func:`irs_cum_leq` so they stay single-rounding."""
+    through :func:`irs_cum_leq` so they stay exact."""
     inst = pl.irs_inst[idx]
     act = np.asarray(active, np.int64)
     ok = (inst > 0) & (act > 0)
@@ -218,25 +234,37 @@ def irs_cumulative(pl: DetPlanes, idx: np.ndarray, wid: np.ndarray,
 
 
 def irs_cum_leq(pl: DetPlanes, idx: np.ndarray, wid: np.ndarray,
-                active: np.ndarray, cutoff: float) -> np.ndarray:
+                active: np.ndarray, cutoff: np.ndarray) -> np.ndarray:
     """Cutoff decision on the cumulative IRS: True where
-    ``irs_hits[wid] / (irs_inst / active) <= cutoff`` (or the guards
-    degrade the IRS to 0.0, which any cutoff >= 0 admits). Evaluated as
-    the single-rounding compare ``hits*act <= cutoff*inst`` — the
-    fixed-point decision contract shared by numpy, C, and XLA."""
+    ``irs_hits[wid] / (irs_inst / active) <= num/den`` (or the guards
+    degrade the IRS to 0.0, which any cutoff >= 0 admits). ``cutoff`` is
+    the ``(..., 2)`` int64 ``(num, den)`` of :func:`ratio`; evaluated as
+    the integer compare ``hits*act*den <= num*inst`` — the decision
+    contract shared by numpy, C, and XLA."""
     inst = pl.irs_inst[idx]
     act = np.asarray(active, np.int64)
     hits = pl.irs_hits[idx, wid % pl.cfg.num_warps]
     bad = (inst <= 0) | (act <= 0)
-    return bad | ((hits * act) <= cutoff * inst.astype(np.float64))
+    return bad | (hits * act * cutoff[..., 1] <= cutoff[..., 0] * inst)
 
 
 def snap_over(hits: np.ndarray, win: np.ndarray, act: np.ndarray,
-              cutoff: float) -> np.ndarray:
+              cutoff: np.ndarray) -> np.ndarray:
     """Windowed-snapshot cutoff decision: True where the fixed-point
-    snapshot ``hits / (win / act)`` exceeds ``cutoff``, evaluated as the
-    single-rounding compare ``hits*act > cutoff*win``."""
-    return (hits * act) > cutoff * np.asarray(win, np.float64)
+    snapshot ``hits / (win / act)`` exceeds the ``(num, den)`` cutoff,
+    evaluated as the integer compare ``hits*act*den > num*win``."""
+    return hits * act * cutoff[..., 1] > cutoff[..., 0] * win
+
+
+def util_below(req_gap: np.ndarray, chan_cyc: np.ndarray,
+               thresh: np.ndarray) -> np.ndarray:
+    """statPCAL's "DRAM underutilized" decision: True where
+    ``min(1, req_gap / chan_cyc) < num/den`` (utilization 0 at cycle 0),
+    as the integer compare ``min(req_gap, chan_cyc)*den < num*chan_cyc``.
+    ``thresh`` is the ``(..., 2)`` int64 ``(num, den)`` of :func:`ratio`."""
+    num, den = thresh[..., 0], thresh[..., 1]
+    busy = np.minimum(req_gap, chan_cyc) * den
+    return np.where(chan_cyc > 0, busy < num * chan_cyc, num > 0)
 
 
 # ----------------------------------------------------------------- CCWS
@@ -273,15 +301,14 @@ def ccws_tick(score: np.ndarray, base: np.ndarray, budget: np.ndarray,
 
 
 # ------------------------------------------------------------- statPCAL
-def statpcal_tick(bypass_active: np.ndarray, util: np.ndarray,
-                  threshold: np.ndarray, base_mask: np.ndarray,
-                  allowed: np.ndarray, bypass: np.ndarray,
-                  idx: np.ndarray) -> np.ndarray:
+def statpcal_tick(bypass_active: np.ndarray, new: np.ndarray,
+                  base_mask: np.ndarray, allowed: np.ndarray,
+                  bypass: np.ndarray, idx: np.ndarray) -> np.ndarray:
     """statPCAL epoch: flip to bypass mode while DRAM bandwidth is
-    underutilized. ``base_mask`` (B, n) holds the static-limit allowed
-    set; masks are rewritten only for cells whose mode flipped. Returns
-    the changed mask aligned with ``idx``."""
-    new = util < threshold[idx]
+    underutilized (``new``, aligned with ``idx`` — see
+    :func:`util_below`). ``base_mask`` (B, n) holds the static-limit
+    allowed set; masks are rewritten only for cells whose mode flipped.
+    Returns the changed mask aligned with ``idx``."""
     changed = new != bypass_active[idx]
     if changed.any():
         sub = idx[changed]

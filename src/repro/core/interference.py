@@ -50,6 +50,7 @@ class DetectorConfig:
     list_entries: int = 64           # §V-F: 64-entry interference/pair lists
     vta_sets: int = 48
     vta_tags_per_set: int = 8
+    # IRS cutoffs, decided as exact rationals (see epoch.ratio)
     high_cutoff: float = 0.01
     low_cutoff: float = 0.005
     high_epoch: int = 5000           # instructions

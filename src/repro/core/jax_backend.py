@@ -2,7 +2,7 @@
 
 This is the third stepper over the same stacked state the numpy and C
 steppers drive (:mod:`repro.core.batched`): the whole batch state is a
-**pytree of int64/bool/float64 arrays** with a leading batch axis, one
+**pytree of int64/bool arrays** with a leading batch axis, one
 lockstep iteration (one scheduler dispatch per live row, the full
 per-access chain, plus the epoch / warp-retirement / timeline servicing
 the C stepper runs in-stepper) is a **pure function** ``state -> state``,
@@ -15,10 +15,13 @@ their sort/scatter kernels entirely.
 **Bit-exactness contract.** Every arithmetic step mirrors the numpy
 stepper elementwise under the fixed-point rules of
 :mod:`repro.core.epoch`: all counters are int64 (x64 mode is enabled in
-a scope around trace and execution — never globally), every cutoff
-decision is the single-rounding float64 compare ``hits*act <> cutoff*win``
-with operands far below 2**53, sorts are stable, and arg-reductions
-break ties on the first index exactly like numpy. ``tests/test_batched.py``
+a scope around trace and execution — never globally), every cutoff or
+threshold decision is the int64 compare ``hits*act*den <> num*win``
+against a ``(num, den)`` knob, sorts are stable, and arg-reductions
+break ties on the first index exactly like numpy. No float64 reaches
+the device, whose float64 may not be IEEE double (TPU v5e emulates it
+with a pair of float32): timeline IPC samples leave it as integer
+pairs and are divided on the host. ``tests/test_batched.py``
 and ``tests/test_jax_backend.py`` pin golden cells and mixed batches
 bit-for-bit across all three steppers.
 
@@ -149,7 +152,7 @@ _STATE_ATTRS = (
     "allowed_pl", "isolated_pl", "bypass_pl", "score_pl",
     "sp_bypass", "sp_base", "swl_next",
     "ciao_stall", "ciao_iso", "stall_len", "iso_len",
-    "tl_cycle", "tl_dipc", "tl_act", "tl_n",
+    "tl_cycle", "tl_act", "tl_n",
 )
 # detector planes stacked in the state with a d_ prefix
 _DET_FIELDS = (
@@ -167,6 +170,10 @@ def _arrays_of(eng):
     state = {k: getattr(eng, k) for k in _STATE_ATTRS}
     for f in _DET_FIELDS:
         state["d_" + f] = getattr(eng.det_pl, f)
+    # timeline IPC samples leave the device as integer (instructions,
+    # cycles) pairs and are divided on the host (_write_back)
+    state["tl_dins"] = np.zeros_like(eng.tl_cycle)
+    state["tl_dcyc"] = np.zeros_like(eng.tl_cycle)
     bump = np.zeros(eng.B, np.int64)
     for b, pol in enumerate(eng.policies):
         if isinstance(pol, CCWSPolicy):
@@ -198,6 +205,9 @@ def _write_back(eng, out) -> None:
         np.copyto(getattr(eng, k), np.asarray(out[k]))
     for f in _DET_FIELDS:
         np.copyto(getattr(eng.det_pl, f), np.asarray(out["d_" + f]))
+    dcyc = np.asarray(out["tl_dcyc"])
+    new = dcyc > 0                     # samples taken in this run
+    eng.tl_dipc[new] = np.asarray(out["tl_dins"])[new] / dcyc[new]
 
 
 # -------------------------------------------------------------- kernels
@@ -207,10 +217,6 @@ def _write_back(eng, out) -> None:
 # new, old))` full-width masked scatters (one target slot per row, so
 # they never collide), and per-cell fallbacks (the VTA FIFO pop) are
 # vectorized over the logical window.
-
-def _f64(a):
-    return a.astype(jnp.float64)
-
 
 def _gated(st, mask, fn, *extra):
     """Run ``fn(st, mask, *extra)`` only when any row is flagged."""
@@ -242,15 +248,12 @@ def _ccws_tick(S, cst, st, m):
 
 
 def _statp_tick(S, cst, st, m):
-    cyc = st["cycle"]
-    # single-SM: the chip-wide request counter is the row's own
-    reqs = st["dram_requests"]
-    util = jnp.where(
-        cyc > 0,
-        _f64(reqs * cst["dram_gap"])
-        / _f64(jnp.maximum(S.dram_channels * cyc, 1)), 0.0)
-    util = jnp.minimum(util, 1.0)
-    new = util < cst["sp_thresh"]
+    # epoch.util_below; single-SM: the chip-wide request counter is the
+    # row's own
+    cc = S.dram_channels * st["cycle"]
+    num, den = cst["sp_thresh"][:, 0], cst["sp_thresh"][:, 1]
+    busy = jnp.minimum(st["dram_requests"] * cst["dram_gap"], cc)
+    new = jnp.where(cc > 0, busy * den < num * cc, num > 0)
     ch = m & (new != st["sp_bypass"])
     bm = st["sp_base"]
     st = dict(st)
@@ -263,12 +266,13 @@ def _statp_tick(S, cst, st, m):
 
 
 def _irs_cum_leq(S, cst, st, wid, act):
-    """Single-rounding cumulative-IRS cutoff (epoch.irs_cum_leq)."""
+    """Integer cumulative-IRS cutoff (epoch.irs_cum_leq)."""
     arB = jnp.arange(st["cycle"].shape[0])
     inst = st["d_irs_inst"]
     hits = st["d_irs_hits"][arB, wid % S.nw]
     bad = (inst <= 0) | (act <= 0)
-    return bad | (_f64(hits * act) <= cst["low_cutoff"] * _f64(inst))
+    cut = cst["low_cutoff"]
+    return bad | (hits * act * cut[:, 1] <= cut[:, 0] * inst)
 
 
 def _ciao_low(S, cst, st, m, act):
@@ -319,7 +323,8 @@ def _ciao_high(S, cst, st, m):
     act = st["d_high_snap_act"][:, None]
     win = st["d_high_snap_win"][:, None]
     hits = st["d_high_snap_hits"][:, np.arange(n) % S.nw]
-    over = _f64(hits * act) > cst["high_cutoff"][:, None] * _f64(win)
+    cut = cst["high_cutoff"][:, None]
+    over = hits * act * cut[..., 1] > cut[..., 0] * win
     cand = m[:, None] & alive & over \
         & (jnp.sum(alive, axis=1) > 1)[:, None]
     order = jnp.argsort(jnp.where(cand, -hits, _DEAD_KEY), axis=1,
@@ -482,11 +487,12 @@ def _timeline(S, st, m):
     kc = jnp.minimum(k, S.tl_cap - 1)           # capacity is proven ample
     cyc, ins = st["cycle"], st["instr"]
     dc = jnp.maximum(cyc - st["last_cycle"], 1)
-    dipc = _f64(ins - st["last_instr"]) / _f64(dc)
     st["tl_cycle"] = st["tl_cycle"].at[arB, kc].set(
         jnp.where(m, cyc, st["tl_cycle"][arB, kc]))
-    st["tl_dipc"] = st["tl_dipc"].at[arB, kc].set(
-        jnp.where(m, dipc, st["tl_dipc"][arB, kc]))
+    st["tl_dins"] = st["tl_dins"].at[arB, kc].set(
+        jnp.where(m, ins - st["last_instr"], st["tl_dins"][arB, kc]))
+    st["tl_dcyc"] = st["tl_dcyc"].at[arB, kc].set(
+        jnp.where(m, dc, st["tl_dcyc"][arB, kc]))
     st["tl_act"] = st["tl_act"].at[arB, kc].set(
         jnp.where(m, act, st["tl_act"][arB, kc]))
     st["tl_n"] = jnp.where(m, k + 1, k)
@@ -828,7 +834,7 @@ def run_engine(eng) -> None:
                            f"{why}")
     S = _static_of(eng)
     state, cst = _arrays_of(eng)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fn = _compiled(S)
         t0 = time.perf_counter()
         out = jax.device_get(fn(state, cst))

@@ -363,8 +363,11 @@ class RunLedger:
         nonce = (f"{worker}.{os.getpid()}.{threading.get_ident()}"
                  f".{time.monotonic_ns()}")
         takeover_of = None
+        # look before reading: a lease linked in between the two must
+        # read as live, not as an unreadable (corrupt) file to steal
+        present = path.exists()
         cur = self.read_lease(key)
-        if cur is not None or path.exists():
+        if cur is not None or present:
             age = now - float(cur.get("ts", 0.0)) if cur else float("inf")
             cur_ttl = float(cur.get("ttl", ttl)) if cur else 0.0
             if cur is not None and age <= cur_ttl \

@@ -134,10 +134,10 @@ class DRAMModel:
         self.requests += 1
         return start - now
 
-    def utilization(self, now: int) -> float:
-        if now <= 0:
-            return 0.0
-        return min(1.0, self.requests * self.gap / (self.channels * now))
+    def load(self, now: int) -> Tuple[int, int]:
+        """``(busy, capacity)`` channel-cycles up to cycle ``now``: the
+        utilization is ``min(1, busy / capacity)``, 0 at cycle 0."""
+        return self.requests * self.gap, self.channels * max(now, 0)
 
 
 class MemoryHierarchy:
@@ -178,10 +178,10 @@ class MemoryHierarchy:
         dram_queue = self.dram.access(line_addr, now + queue)
         return self.lat_dram + queue + dram_queue, "dram"
 
-    def utilization(self, now: int) -> float:
-        """DRAM bandwidth utilization seen at cycle ``now`` (drives the
-        statPCAL bypass decision)."""
-        return self.dram.utilization(now)
+    def dram_load(self, now: int) -> Tuple[int, int]:
+        """DRAM bandwidth load seen at cycle ``now`` as the integer pair of
+        :meth:`DRAMModel.load` (drives the statPCAL bypass decision)."""
+        return self.dram.load(now)
 
     def stats(self) -> Dict[str, int]:
         return {"l2_hits": self.l2.hits, "l2_misses": self.l2.misses,

@@ -34,7 +34,7 @@ IRS of the interfered warp recorded in the pair list.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -83,7 +83,9 @@ class BasePolicy:
         pass
 
     def epoch_tick(self, active: Sequence[int], finished: Sequence[bool],
-                   mem_util: float = 0.0) -> None:
+                   dram_load: Tuple[int, int] = (0, 0)) -> None:
+        """``dram_load`` is the ``(busy, capacity)`` pair of
+        :meth:`repro.core.memory.DRAMModel.load`."""
         pass
 
     def next_epoch_after(self, li: int) -> int:
@@ -193,7 +195,7 @@ class CCWSPolicy(BasePolicy):
     def next_epoch_after(self, li: int) -> int:
         return self._low_epoch_after(li)     # decay runs every epoch
 
-    def epoch_tick(self, active, finished, mem_util=0.0) -> None:
+    def epoch_tick(self, active, finished, dram_load=(0, 0)) -> None:
         fin = np.asarray(finished, bool)
         alive = np.zeros(self.n, bool)
         if active is None:                  # simulator fast path: all warps
@@ -218,7 +220,8 @@ class StatPCALPolicy(BestSWLPolicy):
     def __init__(self, num_warps, detector, limit: int = 48,
                  util_threshold: float = 0.6):
         self._bypass1 = np.zeros(1, bool)
-        self._thresh1 = np.full(1, util_threshold, np.float64)
+        # (1, 2) int64 (num, den): the threshold as epoch.ratio reads it
+        self._thresh1 = np.array([_epoch.ratio(util_threshold)], np.int64)
         self._base_mask = np.zeros(num_warps, bool)
         self.util_threshold = util_threshold
         super().__init__(num_warps, detector, limit)
@@ -253,10 +256,13 @@ class StatPCALPolicy(BestSWLPolicy):
             self.bypass_mask[:] = False
         self.mask_version += 1
 
-    def epoch_tick(self, active, finished, mem_util=0.0) -> None:
+    def epoch_tick(self, active, finished, dram_load=(0, 0)) -> None:
+        busy, capacity = dram_load
         changed = _epoch.statpcal_tick(
-            self._bypass1, np.asarray([mem_util], np.float64),
-            self._thresh1, self._base_mask[None], self.allowed_mask[None],
+            self._bypass1, _epoch.util_below(np.array([busy]),
+                                             np.array([capacity]),
+                                             self._thresh1),
+            self._base_mask[None], self.allowed_mask[None],
             self.bypass_mask[None], _epoch.IDX0)
         if changed[0]:
             self.mask_version += 1
@@ -383,7 +389,7 @@ class CIAOPolicy(BasePolicy):
         self._stall_len[0] = sl + 1
         return True
 
-    def epoch_tick(self, active, finished, mem_util=0.0) -> None:
+    def epoch_tick(self, active, finished, dram_load=(0, 0)) -> None:
         n_active = int(np.count_nonzero(
             self._alive_mask(active, finished)))
         low, high = self.det.poll_epochs(n_active)

@@ -442,13 +442,13 @@ def _backend_ladder(backend: Optional[str]) -> List[str]:
     failing chunk walks down before the per-cell scalar fallback. Every
     rung is bit-exact vs every other (pinned by the golden and engine-
     equality suites), so degrading a chunk cannot change its records —
-    only its speed."""
+    only its speed. ``"jax"`` is the device path and has no ladder: a
+    chunk it cannot run raises (see ``_exec_chunk``) instead of coming
+    back from the CPU steppers under the device's name."""
     from repro.core import _cstep
     have_c = _cstep.available()
     if backend in (None, "auto"):
         return (["c"] if have_c else []) + ["numpy"]
-    if backend == "jax":
-        return ["jax"] + (["c"] if have_c else []) + ["numpy"]
     if backend == "c":
         return ["c", "numpy"]
     return [backend]
@@ -518,10 +518,12 @@ def _run_cells_batched(cells: Sequence[_Cell],
     **Fault isolation** (``strict=False``): each chunk executes behind
     per-future error capture. A failing chunk is retried ``retries``
     times on its first backend, then walks the degradation ladder
-    (jax → C → numpy — all bit-exact, so records are unaffected), then
-    falls back to per-cell scalar execution; cells that still fail are
-    quarantined as :class:`FailedCell` entries while the rest of the
-    sweep completes. ``strict=True`` restores the fail-fast raise.
+    (C → numpy — bit-exact, so records are unaffected), then falls back
+    to per-cell scalar execution; cells that still fail are quarantined
+    as :class:`FailedCell` entries while the rest of the sweep completes.
+    ``strict=True`` restores the fail-fast raise. A single-SM chunk on
+    the ``"jax"`` backend is retried but never degraded: once its retries
+    fail, it raises.
     ``deadline`` (absolute ``time.monotonic()``) cancels chunks that
     have not started and truncates running ones mid-flight; their cells
     come back as ``FailedCell(truncated=True)``. ``run_ledger`` saves a
@@ -767,7 +769,7 @@ def _run_cells_batched(cells: Sequence[_Cell],
                     perf["resplit_chunks"] += 1
                     return ("resplit", n, kids)
                 except Exception:
-                    if strict:
+                    if strict or (be == "jax" and slots == 0):
                         raise
         # every engine rung failed: per-cell scalar fallback, the one
         # path that needs no batched stepper at all

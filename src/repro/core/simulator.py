@@ -431,13 +431,11 @@ class SMSimulator:
                         li += low_epoch
                         det.inst_total, det.irs_inst = li, li - irs_off
                         if fast_l2:
-                            util = dram_requests * dram_gap / \
-                                (dram_channels * cycle) if cycle > 0 else 0.0
-                            if util > 1.0:
-                                util = 1.0
+                            load = (dram_requests * dram_gap,
+                                    dram_channels * cycle)
                         else:
-                            util = mem_sys.utilization(cycle)
-                        epoch_tick(None, done, util)
+                            load = mem_sys.dram_load(cycle)
+                        epoch_tick(None, done, load)
                         irs_off = li - det.irs_inst   # aging moves this
                         if policy.mask_version != mask_ver:
                             mask_ver = policy.mask_version
@@ -639,13 +637,10 @@ class SMSimulator:
             if li >= next_epoch:
                 det.inst_total, det.irs_inst = li, li - irs_off
                 if fast_l2:
-                    util = dram_requests * dram_gap / \
-                        (dram_channels * cycle) if cycle > 0 else 0.0
-                    if util > 1.0:
-                        util = 1.0
+                    load = (dram_requests * dram_gap, dram_channels * cycle)
                 else:
-                    util = mem_sys.utilization(cycle)
-                epoch_tick(None, done, util)
+                    load = mem_sys.dram_load(cycle)
+                epoch_tick(None, done, load)
                 irs_off = li - det.irs_inst      # aging moves this
                 # re-read the trigger table after the tick (stack pushes
                 # switch CIAO back to low-epoch granularity)
@@ -720,9 +715,6 @@ class SMSimulator:
         self.begin()
         self.advance(self.cfg.max_cycles)
         return self.result()
-
-    def _mem_util(self) -> float:
-        return self.mem_sys.utilization(self.cycle)
 
 
 def run_policy_sweep(workload, policies: Sequence[str],

@@ -19,9 +19,13 @@ probe and the data access touch different memories and proceed in parallel.
 Per-stream hit/miss counters are emitted (SMEM-accumulated) as the VTA-style
 feedback the host scheduler consumes.
 
-NOTE: rows are fetched with dynamic loads from an ANY-space ref; a
-production TPU build would issue ``pltpu.make_async_copy`` DMAs with
-double-buffering — semantics identical, validated here in interpret mode.
+Layout. The request indices, streams and isolation bits are scalar-prefetched
+into SMEM. Rows sit on a leading untiled axis — the table is ``(N, 1, W)``,
+the cache ``(C, 1, W)`` and the output block ``(block_t, 1, W)`` — so one row
+is one whole tile and a dynamic row index needs no sublane alignment. A miss
+DMAs its row from the HBM table into its cache slot and waits for it; every
+request then reads its row from the cache. ``W`` counts 32-bit words
+(``ops.py`` packs narrower dtypes).
 """
 from __future__ import annotations
 
@@ -32,63 +36,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUMemorySpace -> MemorySpace
-_ANY_SPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_ANY = _ANY_SPACE.ANY
-
 
 def _gather_kernel(idx_ref, stream_ref, iso_ref, table_ref, out_ref,
-                   stats_ref, tags_scr, data_scr, cnt_scr, *,
+                   stats_ref, tags_scr, data_scr, cnt_scr, sem, *,
                    block_t: int, c_main: int, c_iso: int, num_streams: int,
                    num_blocks: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
-        tags_scr[...] = jnp.full_like(tags_scr, -1)
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
+        def clear(s, c):
+            tags_scr[s] = jnp.int32(-1)
+            return c
+        jax.lax.fori_loop(0, c_main + c_iso, clear, 0)
+        for s in range(num_streams):
+            cnt_scr[s, 0] = jnp.int32(0)
+            cnt_scr[s, 1] = jnp.int32(0)
 
-    def body(i, _):
-        idx = idx_ref[i]
-        stream = stream_ref[i]
-        iso = iso_ref[stream]
+    def body(i, c):
+        g = step * block_t + i
+        idx = idx_ref[g]
+        stream = stream_ref[g]
         # partition choice: direct-mapped slot in main or isolated region
-        slot_main = jax.lax.rem(idx, jnp.int32(c_main))
-        slot_iso = jnp.int32(c_main) + jax.lax.rem(idx, jnp.int32(max(c_iso, 1)))
-        slot = jnp.where(iso > 0, slot_iso, slot_main)
-        hit = tags_scr[slot] == idx
+        slot = jnp.where(iso_ref[stream] > 0,
+                         c_main + jax.lax.rem(idx, jnp.int32(c_iso)),
+                         jax.lax.rem(idx, jnp.int32(c_main)))
+        miss = tags_scr[slot] != idx
 
-        def on_hit():
-            return pl.load(data_scr, (pl.ds(slot, 1), slice(None)))
-
-        def on_miss():
-            row = pl.load(table_ref, (pl.ds(idx, 1), slice(None)))
-            pl.store(data_scr, (pl.ds(slot, 1), slice(None)), row)
+        @pl.when(miss)
+        def _fill():
+            copy = pltpu.make_async_copy(table_ref.at[idx],
+                                         data_scr.at[slot], sem)
+            copy.start()
+            copy.wait()
             tags_scr[slot] = idx
-            return row
 
-        row = jax.lax.cond(hit, on_hit, on_miss)
-        pl.store(out_ref, (pl.ds(i, 1), slice(None)), row)
+        out_ref[i] = data_scr[slot]
         # per-stream hit/miss counters (VTA-style feedback)
-        col = jnp.where(hit, 0, 1)
-        cnt_scr[stream, col] += 1
-        return 0
+        col = miss.astype(jnp.int32)
+        cnt_scr[stream, col] = cnt_scr[stream, col] + 1
+        return c
 
     jax.lax.fori_loop(0, block_t, body, 0)
 
     @pl.when(step == num_blocks - 1)
     def _emit():
-        stats_ref[...] = cnt_scr[...]
+        for s in range(num_streams):
+            stats_ref[s, 0] = cnt_scr[s, 0]
+            stats_ref[s, 1] = cnt_scr[s, 1]
 
 
 def ciao_gather_kernel(table, indices, streams, iso_map, *,
                        c_main: int = 256, c_iso: int = 64,
                        block_t: int = 128, interpret: bool = False):
-    """table: (N, D); indices/streams: (T,) int32; iso_map: (S,) int32.
-    Returns (out (T, D), stats (S, 2) int32 [hits, misses] per stream)."""
+    """table: (N, 1, W) 32-bit words; indices/streams: (T,) int32 with
+    ``T % block_t == 0``; iso_map: (S,) int32. Returns
+    (out (T, 1, W), stats (S, 2) int32 [hits, misses] per stream)."""
     t = indices.shape[0]
-    n, d = table.shape
+    n, one, w = table.shape
+    assert one == 1 and table.dtype.itemsize == 4, table.shape
     num_streams = iso_map.shape[0]
+    c_iso = max(c_iso, 1)
     nb = t // block_t
 
     kernel = functools.partial(
@@ -97,29 +105,25 @@ def ciao_gather_kernel(table, indices, streams, iso_map, *,
 
     return pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block_t,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_t,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((num_streams,), lambda i: (0,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=_ANY),  # table in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-            pl.BlockSpec((num_streams, 2), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(nb,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # table in HBM
+            out_specs=[
+                pl.BlockSpec((block_t, 1, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            scratch_shapes=[
+                pltpu.SMEM((c_main + c_iso,), jnp.int32),         # tags
+                pltpu.VMEM((c_main + c_iso, 1, w), table.dtype),  # data
+                pltpu.SMEM((num_streams, 2), jnp.int32),          # counters
+                pltpu.SemaphoreType.DMA(()),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((t, d), table.dtype),
+            jax.ShapeDtypeStruct((t, 1, w), table.dtype),
             jax.ShapeDtypeStruct((num_streams, 2), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.SMEM((c_main + max(c_iso, 1),), jnp.int32),   # tags
-            pltpu.VMEM((c_main + max(c_iso, 1), d), table.dtype),  # data
-            pltpu.SMEM((num_streams, 2), jnp.int32),            # counters
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(indices, streams, iso_map, table)

@@ -20,6 +20,7 @@ NEG_INF = -1e30
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, scale: float, softcap: float, block_kv: int,
                    num_kv_blocks: int):
+    bi = pl.program_id(0)
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -36,7 +37,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     kpos = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = kpos < len_ref[0]
+    valid = kpos < len_ref[bi]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -70,8 +71,9 @@ def decode_attention_kernel(q, k, v, lengths, *, scale: float,
         kernel,
         grid=(bh, nkv),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,),
-                         memory_space=pltpu.SMEM),
+            # whole lengths vector in SMEM: a rank-1 SMEM block must be the
+            # full array or a multiple of 128 long
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),

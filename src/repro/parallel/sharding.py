@@ -20,7 +20,7 @@ from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axes = Union[None, str, Tuple[str, ...]]
 
@@ -197,6 +197,12 @@ class ShardEnv:
 
 def make_env(mesh: Mesh, mode: str = "train",
              overrides: Sequence[Tuple[str, Axes]] = ()) -> ShardEnv:
+    """The model places activations with ``with_sharding_constraint``, which
+    only Auto mesh axes accept, so the env's mesh is always all-Auto, also
+    when ``mesh`` comes from ``jax.make_mesh`` (whose axes are Explicit)."""
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     rules = dict(RULE_SETS[mode])
     for k, v in overrides:
         rules[k] = v

@@ -1,0 +1,105 @@
+"""Compile the main-path kernels and the gemma2-2b decode step for a TPU v5e
+chip that is described, not attached: the TPU compiler refuses here what it
+would refuse on the chip (unaligned blocks, SMEM layouts, memory that does
+not fit), at no chip time. Nothing runs, so nothing here checks a value.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.configs.base import RunConfig
+from repro.kernels.ciao_gather.ops import ciao_gather
+from repro.kernels.decode_attn.ops import decode_attention
+from repro.kernels.flash_attn.ops import flash_attention
+from repro.models import model as M
+from repro.parallel.sharding import local_env
+
+HQ, HKV, D, CAP = 8, 4, 256, 50.0       # gemma2-2b attention widths
+
+
+@pytest.fixture(scope="module")
+def topo():
+    pytest.importorskip("libtpu", reason="the TPU compiler is not installed")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip, so keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    c = _compile(one_chip,
+                 lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                 softcap=CAP),
+                 ((2, 2048, HQ, D), jnp.bfloat16),
+                 ((2, 2048, HKV, D), jnp.bfloat16),
+                 ((2, 2048, HKV, D), jnp.bfloat16))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_decode_attention_compiles_for_v5e(one_chip):
+    c = _compile(one_chip,
+                 lambda q, k, v, n: decode_attention(q, k, v, n,
+                                                     softcap=CAP),
+                 ((8, 1, HQ, D), jnp.bfloat16),
+                 ((8, 8192, HKV, D), jnp.bfloat16),
+                 ((8, 8192, HKV, D), jnp.bfloat16),
+                 ((8,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ciao_gather_compiles_for_v5e(one_chip, dtype):
+    c = _compile(one_chip, ciao_gather,
+                 ((262144, 256), dtype), ((65536,), jnp.int32),
+                 ((65536,), jnp.int32), ((4,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gemma2_decode_step_compiles_for_v5e(one_chip):
+    cfg = get_config("gemma2-2b")
+    run = RunConfig(remat_policy="none", param_dtype="bfloat16")
+    env = local_env()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(M.param_shapes(cfg, run))
+    cache = on_chip(M.cache_struct(cfg, 8, 2048))
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    c = jax.jit(lambda p, t, n, kv: M.decode_step(env, cfg, p, t, n, kv,
+                                                  run),
+                donate_argnums=3).lower(params, tok, pos, cache).compile()
+    mem = c.memory_analysis()
+    # full-width weights (5.2 GB) and cache must fit one 16 GB chip
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 5e9 < mem.argument_size_in_bytes and used < 16e9

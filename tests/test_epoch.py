@@ -50,7 +50,7 @@ def _rand_cell(rng, policy_name):
     if isinstance(pol, StatPCALPolicy):
         if rng.integers(0, 2):
             # flip into bypass mode through the real epoch path
-            pol.epoch_tick(None, [False] * N, 0.0)
+            pol.epoch_tick(None, [False] * N, (0, 0))
     if isinstance(pol, CIAOPolicy):
         # push a few legitimate stack entries (stall via the public
         # API; isolation white-box, as high_epoch_tick would)
@@ -67,7 +67,7 @@ def _rand_cell(rng, policy_name):
     return det, pol
 
 
-def _batch_tick(dets, pols, done, util):
+def _batch_tick(dets, pols, done, req_gap, chan_cyc):
     """Mirror of BatchedSMEngine._epoch_batch over freshly adopted
     planes (the engine's exact call sequence, minus the stepper)."""
     k = len(dets)
@@ -80,7 +80,7 @@ def _batch_tick(dets, pols, done, util):
     base = np.zeros(k, np.int64)
     budget = np.zeros(k, np.int64)
     sp_byp = np.zeros(k, bool)
-    sp_thr = np.zeros(k, np.float64)
+    sp_thr = np.zeros((k, 2), np.int64)
     sp_base = np.zeros((k, N), bool)
     stall = np.full((k, N), -1, np.int64)
     iso = np.full((k, N), -1, np.int64)
@@ -103,8 +103,9 @@ def _batch_tick(dets, pols, done, util):
     if isinstance(pol0, CCWSPolicy):
         _epoch.ccws_tick(score, base, budget, ~done, allowed, idx)
     elif isinstance(pol0, StatPCALPolicy):
-        _epoch.statpcal_tick(sp_byp, util, sp_thr, sp_base, allowed,
-                             bypass, idx)
+        _epoch.statpcal_tick(
+            sp_byp, _epoch.util_below(req_gap, chan_cyc, sp_thr[idx]),
+            sp_base, allowed, bypass, idx)
     elif isinstance(pol0, CIAOPolicy):
         n_act = np.count_nonzero(allowed & ~done, axis=1)
         low, high = _epoch.poll_epochs(pl, idx, n_act)
@@ -135,14 +136,16 @@ def test_batched_kernels_equal_per_cell_objects(seed, family):
     rng = np.random.default_rng(seed ^ 0xC1A0)
     done = rng.integers(0, 2, (K, N)).astype(bool)
     done[:, 0] = False                  # keep at least one warp alive
-    util = rng.random(K)
+    # DRAM load (busy, capacity): utilization min(1, busy / capacity)
+    req_gap = rng.integers(0, 1000, K)
+    chan_cyc = rng.integers(0, 1000, K)
 
     # A: the per-cell object path (batch-of-1 views)
-    for (det, pol), d, u in zip(cells_a, done, util):
-        pol.epoch_tick(None, d, float(u))
+    for (det, pol), d, g, c in zip(cells_a, done, req_gap, chan_cyc):
+        pol.epoch_tick(None, d, (int(g), int(c)))
     # B: one batched kernel pass over stacked planes
     pl_b = _batch_tick([d for d, _ in cells_b],
-                       [p for _, p in cells_b], done, util)
+                       [p for _, p in cells_b], done, req_gap, chan_cyc)
 
     for b, ((det_a, pol_a), (det_b, pol_b)) in enumerate(
             zip(cells_a, cells_b)):
@@ -171,22 +174,15 @@ def test_batched_kernels_equal_per_cell_objects(seed, family):
 @settings(max_examples=80, deadline=None)
 @given(st.integers(0, 10**9), st.integers(0, 1 << 11))
 def test_cutoff_decisions_match_exact_rationals(seed, knum):
-    """The fixed-point scaling contract behind the shared cutoff
-    decisions: ``irs_cum_leq`` / ``snap_over`` evaluate the IRS compare
-    as the single-rounding product compare ``hits*act <> cutoff*X``. For
-    dyadic cutoffs (k/1024 — denominator a power of two) and counters in
-    the simulator's range both products are exactly representable in
-    f64, so the decision must equal arbitrary-precision rational
-    arithmetic bit-for-bit. This is what lets the numpy, C, and XLA
-    steppers share one decision kernel without drift. (The shipped
-    defaults 0.01/0.005 are non-dyadic: there the compare is still
-    single-rounding — one IEEE rounding total — and all three backends
-    evaluate the identical expression, which the golden and mixed-batch
-    equality tests pin.)"""
+    """The fixed-point contract behind the shared cutoff decisions:
+    ``irs_cum_leq`` / ``snap_over`` evaluate the IRS compare as the int64
+    compare ``hits*act*den <> num*X`` against the knob's ``(num, den)``
+    from ``epoch.ratio``. For dyadic (k/1024) and decimal (k/1000) knobs
+    ``ratio`` is exact, so the decision must equal arbitrary-precision
+    rational arithmetic bit-for-bit. This is what lets the numpy, C, and
+    XLA steppers share one decision kernel without drift, on any chip.
+    ``util_below`` is held to the same standard."""
     rng = np.random.default_rng(seed)
-    cutoff = knum / 1024.0
-    exact = Fraction(knum, 1024)
-    assert Fraction(cutoff) == exact        # dyadic: exactly a f64
     pl = _epoch.DetPlanes.alloc(K, _det_cfg())
     pl.irs_inst[:] = rng.integers(0, 1 << 20, K)
     pl.irs_inst[rng.integers(0, K)] = 0     # exercise the 0-IRS guard
@@ -194,22 +190,34 @@ def test_cutoff_decisions_match_exact_rationals(seed, knum):
     idx = np.arange(K, dtype=np.int64)
     wid = rng.integers(0, N, K)
     act = rng.integers(0, N + 1, K)
-
-    got = _epoch.irs_cum_leq(pl, idx, wid, act, cutoff)
-    for b in range(K):
-        inst, a = int(pl.irs_inst[b]), int(act[b])
-        h = int(pl.irs_hits[b, wid[b] % N])
-        want = (inst <= 0 or a <= 0) or Fraction(h * a) <= exact * inst
-        assert bool(got[b]) == want, f"irs_cum_leq cell {b}"
-
     hits = rng.integers(0, 1 << 16, (K, N)).astype(np.int64)
     win = rng.integers(0, 1 << 20, K).astype(np.int64)
-    got2 = _epoch.snap_over(hits, win[:, None], act[:, None], cutoff)
-    for b in range(K):
-        for w in range(N):
-            want = Fraction(int(hits[b, w]) * int(act[b])) \
-                > exact * int(win[b])
-            assert bool(got2[b, w]) == want, f"snap_over {b},{w}"
+    req_gap = rng.integers(0, 1 << 20, K)
+    chan_cyc = rng.integers(0, 1 << 20, K)
+    chan_cyc[rng.integers(0, K)] = 0        # cycle 0: utilization 0
+
+    for exact in (Fraction(knum, 1024), Fraction(knum, 1000)):
+        cut = np.array(_epoch.ratio(float(exact)), np.int64)
+        assert Fraction(int(cut[0]), int(cut[1])) == exact
+        got = _epoch.irs_cum_leq(pl, idx, wid, act, cut)
+        for b in range(K):
+            inst, a = int(pl.irs_inst[b]), int(act[b])
+            h = int(pl.irs_hits[b, wid[b] % N])
+            want = (inst <= 0 or a <= 0) or Fraction(h * a) <= exact * inst
+            assert bool(got[b]) == want, f"irs_cum_leq cell {b}"
+
+        got2 = _epoch.snap_over(hits, win[:, None], act[:, None], cut)
+        for b in range(K):
+            for w in range(N):
+                want = Fraction(int(hits[b, w]) * int(act[b])) \
+                    > exact * int(win[b])
+                assert bool(got2[b, w]) == want, f"snap_over {b},{w}"
+
+        got3 = _epoch.util_below(req_gap, chan_cyc, cut)
+        for b in range(K):
+            g, c = int(req_gap[b]), int(chan_cyc[b])
+            util = min(Fraction(1), Fraction(g, c)) if c else Fraction(0)
+            assert bool(got3[b]) == (util < exact), f"util_below {b}"
 
 
 @pytest.mark.parametrize("family", ["ccws", "ciao-c"])
@@ -225,10 +233,11 @@ def test_repeated_ticks_stay_equal(family):
     for step in range(4):
         for (det, pol) in cells_a:
             det.on_instruction(60)
-            pol.epoch_tick(None, done[0], 0.0)
+            pol.epoch_tick(None, done[0], (0, 0))
         for det in dets_b:
             det.on_instruction(60)
-        _batch_tick(dets_b, pols_b, done, np.zeros(K))
+        _batch_tick(dets_b, pols_b, done, np.zeros(K, np.int64),
+                    np.zeros(K, np.int64))
         for (det_a, pol_a), pol_b in zip(cells_a, pols_b):
             np.testing.assert_array_equal(
                 pol_a.allowed_mask, pol_b.allowed_mask, f"step {step}")

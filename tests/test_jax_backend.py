@@ -12,6 +12,8 @@ Mirrors ``tests/test_batched.py``'s pinning for the third stepper:
 * the runner: ``engine="jax"`` records equal ``engine="batched"`` on a
   grid that mixes batchable cells, an MSHR-gated variant (per-cell
   fallback) and a multi-SM grid (jax chunks fall back to "auto").
+* the runner never answers for a failing jax stepper with another one:
+  ``run_grid(engine="jax")`` raises.
 * the gating contract: multi-SM / object-policy batches raise.
 * the batch axis is vmap-able: one jitted iteration under ``jax.vmap``
   over an outer grid axis equals two independent iterations.
@@ -99,6 +101,35 @@ def test_runner_engine_jax_matches_batched(tmp_path, monkeypatch):
                                                     engine="batched")
 
 
+@pytest.mark.parametrize("inject", ["stepper", "dispatch"])
+def test_runner_engine_jax_failure_raises(tmp_path, monkeypatch, inject):
+    """A failing jax stepper raises out of run_grid(engine="jax"), also
+    without strict=True: no C, numpy or scalar rung answers in its
+    place, and the retry still runs on the jax rung first."""
+    monkeypatch.setenv("REPRO_WORKLOAD_CACHE_DIR", str(tmp_path))
+    from repro.core import faults
+    from repro.core.runner import ExperimentGrid, run_grid
+    grid = ExperimentGrid(name="t3", workloads=("syrk",),
+                          policies=("gto", "ciao-c"), scale=0.05)
+    calls = []
+
+    def broken(eng):
+        calls.append(eng.B)
+        raise RuntimeError("device stepper failed")
+
+    if inject == "stepper":
+        monkeypatch.setattr(jax_backend, "run_engine", broken)
+        with pytest.raises(RuntimeError, match="device stepper failed"):
+            run_grid(grid, engine="jax", retries=1)
+        assert len(calls) == 2               # first try + one retry
+    else:
+        with faults.injected("chunk.dispatch@*=raise"), \
+                pytest.raises(faults.InjectedFault):
+            run_grid(grid, engine="jax", retries=1)
+        # the C stepper still answers the same grid when asked by name
+        assert len(run_grid(grid, engine="batched")) == 2
+
+
 def test_runner_engine_jax_multi_sm_falls_back(tmp_path, monkeypatch):
     """Multi-SM grids under engine="jax" fall back to the default
     stepper per chunk and still produce equal records."""
@@ -127,7 +158,7 @@ def test_gating_contract(monkeypatch):
         eng.run()
 
     class OddPolicy(GTOPolicy):
-        def epoch_tick(self, active, finished, mem_util=0.0):
+        def epoch_tick(self, active, finished, dram_load=(0, 0)):
             pass        # any override outside the known families
 
     real = batched_mod.make_policy
@@ -153,7 +184,7 @@ def test_iteration_is_vmappable():
                            BatchCell(wl, "ciao-c")], backend="jax")
     S = jax_backend._static_of(eng)
     state, cst = jax_backend._arrays_of(eng)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         step = jax.jit(
             lambda st, c: jax_backend._iteration(S, c, dict(st)))
         one = {k: np.asarray(v) for k, v in step(state, cst).items()}

@@ -4,7 +4,7 @@ workload-cache corruption recovery path.
 
 Every scenario here drives ``run_grid`` through ``faults.injected`` and
 checks the central invariant: because every rung of the backend ladder
-(jax / C / numpy / per-cell scalar) is bit-exact, *recovery never
+(C / numpy / per-cell scalar) is bit-exact, *recovery never
 changes records* — a run that retried, degraded, or regenerated a cache
 file returns exactly the records of an undisturbed run.
 """
