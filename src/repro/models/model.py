@@ -35,6 +35,26 @@ from repro.models import rglru as rglru_mod
 from repro.models import ssd as ssd_mod
 from repro.parallel.sharding import ShardEnv
 
+# The named scopes (``jax.named_scope``) of the train, prefill and decode
+# programs. They are metadata only: each compiled instruction's ``op_name``
+# carries the scopes it lies in, and a profile's operations can be summed by
+# them. Every block's work lies in exactly one block scope.
+SCOPES = (
+    "embed",       # token (and frontend or encoder) embedding
+    "layers",      # the layer scan and the remainder blocks; what lies under
+                   # it and under no block scope is the scan's own work (the
+                   # per-layer slicing and write-back of its xs and ys)
+    "attn_qkv",    # ln1, the q/k/v projections, rotary
+    "kv_write",    # writing the new keys and values into the cache
+    "attend",      # the attention itself (attention_core / decode_attend)
+    "attn_out",    # the output projection and its residual
+    "cross_attn",  # the cross-attention of an encoder-decoder block
+    "mlp",         # ln2, the MLP or MoE, and its residual
+    "rglru",       # ln1, the RG-LRU mixer and its residual
+    "ssd",         # ln1, the SSD mixer and its residual
+    "head",        # the final norm and the logits (and the loss)
+)
+
 
 # ============================================================ block builders
 def _block_init(cfg: ModelConfig, kind: str, key, dtype):
@@ -168,87 +188,40 @@ def _mask_kind(cfg, kind, prefix_len):
     return "causal"
 
 
-def _attn_train(env, cfg, bp, x, kind, positions, prefix_len, chunk,
-                enc_out=None, enc_positions=None, encoder_self=False):
-    h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
-    q, k, v = attn.project_qkv(env, cfg, bp["attn"], h,
-                               positions=positions)
-    mask = "full" if encoder_self else _mask_kind(cfg, kind, prefix_len)
-    o = attn.attention_core(env, cfg, q, k, v, mask_kind=mask,
-                            prefix_len=prefix_len, chunk=chunk)
-    out = attn.output_proj(env, cfg, bp["attn"], o)
-    if cfg.parallel_block:
-        m = L.mlp_apply(env, bp["mlp"], h, cfg.mlp_activation)
-        return x + out + m, (k, v)
-    x = x + out
-    h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
-    if cfg.is_encoder_decoder and enc_out is not None:
-        cq, ck, cv = attn.project_qkv(
-            env, cfg, bp["cross"], L.rmsnorm(bp["ln_cross"], x, cfg.norm_eps),
-            kv_x=enc_out, positions=positions, kv_positions=enc_positions,
-            use_rope=False)
-        co = attn.attention_core(env, cfg, cq, ck, cv, mask_kind="full",
-                                 chunk=chunk)
-        x = x + attn.output_proj(env, cfg, bp["cross"], co)
-        h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
-    if cfg.num_experts:
-        f = moe_mod.moe_apply(env, cfg, bp["moe"], h2)
-        if cfg.moe_dense_residual:
-            f = f + L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
-    else:
-        f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
-    return x + f, (k, v)
-
-
-def _ffn_part(env, cfg, bp, x):
-    h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
-    if cfg.num_experts:
-        f = moe_mod.moe_apply(env, cfg, bp["moe"], h2)
-        if cfg.moe_dense_residual:
-            f = f + L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
-    else:
-        f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
-    return x + f
-
-
-def apply_block_train(env, cfg, kind, bp, x, *, positions, prefix_len,
-                      chunk, enc_out=None, enc_positions=None):
-    if kind in ATTN_BLOCKS:
-        x, _ = _attn_train(env, cfg, bp, x, kind, positions, prefix_len,
-                           chunk, enc_out, enc_positions)
-        return x
-    if kind == BLOCK_RGLRU:
-        x = x + rglru_mod.rglru_forward(
-            env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
-        return _ffn_part(env, cfg, bp, x)
-    if kind == BLOCK_SSD:
-        return x + ssd_mod.ssd_forward(
-            env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
-    raise ValueError(kind)
-
-
-def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
-                        prefix_len, chunk, enc_out=None, enc_positions=None):
-    """Like train, but fills ``cache_entry`` and returns (x, new_entry)."""
-    if kind in ATTN_BLOCKS:
+def _qkv(env, cfg, bp, x, positions):
+    with jax.named_scope("attn_qkv"):
         h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
-        q, k, v = attn.project_qkv(env, cfg, bp["attn"], h, positions=positions)
-        mask = _mask_kind(cfg, kind, prefix_len)
+        q, k, v = attn.project_qkv(env, cfg, bp["attn"], h,
+                                   positions=positions)
+    return h, q, k, v
+
+
+def _attn_out(env, cfg, bp, x, o):
+    with jax.named_scope("attn_out"):
+        return x + attn.output_proj(env, cfg, bp["attn"], o)
+
+
+def _self_attn(env, cfg, bp, x, mask, positions, prefix_len, chunk):
+    """Self-attention of a block over a whole sequence and its residual;
+    returns (x, ln1 output, k, v)."""
+    h, q, k, v = _qkv(env, cfg, bp, x, positions)
+    with jax.named_scope("attend"):
         o = attn.attention_core(env, cfg, q, k, v, mask_kind=mask,
                                 prefix_len=prefix_len, chunk=chunk)
-        out = attn.output_proj(env, cfg, bp["attn"], o)
-        new = dict(cache_entry)
-        if kind == BLOCK_LOCAL_ATTN and cache_entry["k"].shape[1] < k.shape[1]:
-            new["k"], new["v"] = attn.write_ring_cache(
-                cache_entry["k"], cache_entry["v"], k, v)
-        else:
-            new["k"], new["v"] = attn.write_full_cache(
-                cache_entry["k"], cache_entry["v"], k, v, 0)
-        if cfg.parallel_block:
-            m = L.mlp_apply(env, bp["mlp"], h, cfg.mlp_activation)
-            return x + out + m, new
-        x = x + out
-        if cfg.is_encoder_decoder and enc_out is not None:
+    return _attn_out(env, cfg, bp, x, o), h, k, v
+
+
+def _attn_tail(env, cfg, bp, x, h, *, positions, chunk, enc_out,
+               enc_positions):
+    """What follows a block's self-attention over a whole sequence: the MLP
+    beside it in a parallel block, else the cross-attention of an
+    encoder-decoder block and the MLP. Returns (x, (ck, cv) or None)."""
+    if cfg.parallel_block:
+        with jax.named_scope("mlp"):
+            return x + L.mlp_apply(env, bp["mlp"], h, cfg.mlp_activation), None
+    cross = None
+    if cfg.is_encoder_decoder and enc_out is not None:
+        with jax.named_scope("cross_attn"):
             hc = L.rmsnorm(bp["ln_cross"], x, cfg.norm_eps)
             cq, ck, cv = attn.project_qkv(
                 env, cfg, bp["cross"], hc, kv_x=enc_out, positions=positions,
@@ -256,61 +229,125 @@ def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
             co = attn.attention_core(env, cfg, cq, ck, cv, mask_kind="full",
                                      chunk=chunk)
             x = x + attn.output_proj(env, cfg, bp["cross"], co)
-            new["ck"], new["cv"] = ck.astype(new["ck"].dtype), cv.astype(new["cv"].dtype)
-        return _ffn_part(env, cfg, bp, x), new
+        cross = (ck, cv)
+    return _ffn_part(env, cfg, bp, x), cross
+
+
+def _ffn_part(env, cfg, bp, x):
+    with jax.named_scope("mlp"):
+        h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
+        if cfg.num_experts:
+            f = moe_mod.moe_apply(env, cfg, bp["moe"], h2)
+            if cfg.moe_dense_residual:
+                f = f + L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
+        else:
+            f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
+        return x + f
+
+
+def apply_block_train(env, cfg, kind, bp, x, *, positions, prefix_len,
+                      chunk, enc_out=None, enc_positions=None):
+    if kind in ATTN_BLOCKS:
+        x, h, _, _ = _self_attn(env, cfg, bp, x,
+                                _mask_kind(cfg, kind, prefix_len),
+                                positions, prefix_len, chunk)
+        return _attn_tail(env, cfg, bp, x, h, positions=positions,
+                          chunk=chunk, enc_out=enc_out,
+                          enc_positions=enc_positions)[0]
     if kind == BLOCK_RGLRU:
-        out, (h_last, conv) = rglru_mod.rglru_forward(
-            env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
-            return_state=True)
-        x = x + out
+        with jax.named_scope("rglru"):
+            x = x + rglru_mod.rglru_forward(
+                env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
+        return _ffn_part(env, cfg, bp, x)
+    if kind == BLOCK_SSD:
+        with jax.named_scope("ssd"):
+            return x + ssd_mod.ssd_forward(
+                env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
+    raise ValueError(kind)
+
+
+def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
+                        prefix_len, chunk, enc_out=None, enc_positions=None):
+    """Like train, but fills ``cache_entry`` and returns (x, new_entry)."""
+    if kind in ATTN_BLOCKS:
+        x, h, k, v = _self_attn(env, cfg, bp, x,
+                                _mask_kind(cfg, kind, prefix_len),
+                                positions, prefix_len, chunk)
+        new = dict(cache_entry)
+        with jax.named_scope("kv_write"):
+            if kind == BLOCK_LOCAL_ATTN and \
+                    cache_entry["k"].shape[1] < k.shape[1]:
+                new["k"], new["v"] = attn.write_ring_cache(
+                    cache_entry["k"], cache_entry["v"], k, v)
+            else:
+                new["k"], new["v"] = attn.write_full_cache(
+                    cache_entry["k"], cache_entry["v"], k, v, 0)
+        x, cross = _attn_tail(env, cfg, bp, x, h, positions=positions,
+                              chunk=chunk, enc_out=enc_out,
+                              enc_positions=enc_positions)
+        if cross is not None:
+            with jax.named_scope("kv_write"):
+                new["ck"], new["cv"] = (c.astype(new[n].dtype)
+                                        for c, n in zip(cross, ("ck", "cv")))
+        return x, new
+    if kind == BLOCK_RGLRU:
+        with jax.named_scope("rglru"):
+            out, (h_last, conv) = rglru_mod.rglru_forward(
+                env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
+                return_state=True)
+            x = x + out
         return _ffn_part(env, cfg, bp, x), {"h": h_last, "conv": conv}
     if kind == BLOCK_SSD:
-        out, (h_last, conv) = ssd_mod.ssd_forward(
-            env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
-            return_state=True)
-        return x + out, {"h": h_last, "conv": conv}
+        with jax.named_scope("ssd"):
+            out, (h_last, conv) = ssd_mod.ssd_forward(
+                env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
+                return_state=True)
+            return x + out, {"h": h_last, "conv": conv}
     raise ValueError(kind)
 
 
 def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos):
     """One-token step. x_t: (B, 1, d); pos: (B,) absolute position."""
     if kind in ATTN_BLOCKS:
-        h = L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps)
-        q, k, v = attn.project_qkv(env, cfg, bp["attn"], h,
-                                   positions=pos[:, None])
+        h, q, k, v = _qkv(env, cfg, bp, x_t, pos[:, None])
         ring = kind == BLOCK_LOCAL_ATTN
         new = dict(cache_entry)
-        new["k"], new["v"] = _decode_write_vec(
-            cache_entry["k"], cache_entry["v"], k, v, pos, ring)
+        with jax.named_scope("kv_write"):
+            new["k"], new["v"] = _decode_write_vec(
+                cache_entry["k"], cache_entry["v"], k, v, pos, ring)
         window = cfg.local_window if ring else 0
-        o = attn.decode_attend(env, cfg, q, new["k"], new["v"], pos,
-                               ring=ring, window=window)
-        out = attn.output_proj(env, cfg, bp["attn"], o)
+        with jax.named_scope("attend"):
+            o = attn.decode_attend(env, cfg, q, new["k"], new["v"], pos,
+                                   ring=ring, window=window)
+        x_t = _attn_out(env, cfg, bp, x_t, o)
         if cfg.parallel_block:
-            m = L.mlp_apply(env, bp["mlp"], h, cfg.mlp_activation)
-            return x_t + out + m, new
-        x_t = x_t + out
+            with jax.named_scope("mlp"):
+                return x_t + L.mlp_apply(env, bp["mlp"], h,
+                                         cfg.mlp_activation), new
         if cfg.is_encoder_decoder and "ck" in cache_entry:
-            hc = L.rmsnorm(bp["ln_cross"], x_t, cfg.norm_eps)
-            cq = jnp.einsum("bsd,dhk->bshk", hc, bp["cross"]["wq"])
-            if cfg.attn_bias:
-                cq = cq + bp["cross"]["bq"]
-            co = attn.decode_attend(env, cfg, cq, cache_entry["ck"],
-                                    cache_entry["cv"], pos, ring=False,
-                                    cross=True)
-            x_t = x_t + attn.output_proj(env, cfg, bp["cross"], co)
+            with jax.named_scope("cross_attn"):
+                hc = L.rmsnorm(bp["ln_cross"], x_t, cfg.norm_eps)
+                cq = jnp.einsum("bsd,dhk->bshk", hc, bp["cross"]["wq"])
+                if cfg.attn_bias:
+                    cq = cq + bp["cross"]["bq"]
+                co = attn.decode_attend(env, cfg, cq, cache_entry["ck"],
+                                        cache_entry["cv"], pos, ring=False,
+                                        cross=True)
+                x_t = x_t + attn.output_proj(env, cfg, bp["cross"], co)
         return _ffn_part(env, cfg, bp, x_t), new
     if kind == BLOCK_RGLRU:
-        out, (h_new, conv) = rglru_mod.rglru_step(
-            env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
-            (cache_entry["h"], cache_entry["conv"]))
-        x_t = x_t + out
+        with jax.named_scope("rglru"):
+            out, (h_new, conv) = rglru_mod.rglru_step(
+                env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
+                (cache_entry["h"], cache_entry["conv"]))
+            x_t = x_t + out
         return _ffn_part(env, cfg, bp, x_t), {"h": h_new, "conv": conv}
     if kind == BLOCK_SSD:
-        out, (h_new, conv) = ssd_mod.ssd_step(
-            env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
-            (cache_entry["h"], cache_entry["conv"]))
-        return x_t + out, {"h": h_new, "conv": conv}
+        with jax.named_scope("ssd"):
+            out, (h_new, conv) = ssd_mod.ssd_step(
+                env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
+                (cache_entry["h"], cache_entry["conv"]))
+            return x_t + out, {"h": h_new, "conv": conv}
     raise ValueError(kind)
 
 
@@ -336,21 +373,17 @@ def _remat(fn, policy: str):
 
 def _run_stack_train(env, cfg, params, x, *, positions, prefix_len, run,
                      enc_out=None, enc_positions=None, encoder: bool = False):
-    pattern = ("global",) * 1 if encoder else cfg.pattern
     stack = params.get("stack")
     chunk = run.attn_chunk
 
     def body(x, lp):
         if encoder:
-            h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
-            q, k, v = attn.project_qkv(env, cfg, lp["attn"], h,
-                                       positions=positions)
-            o = attn.attention_core(env, cfg, q, k, v, mask_kind="full",
-                                    chunk=chunk)
-            x = x + attn.output_proj(env, cfg, lp["attn"], o)
-            h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
-            x = x + L.mlp_apply(env, lp["mlp"], h2, cfg.mlp_activation)
-            return x, None
+            x, _, _, _ = _self_attn(env, cfg, lp, x, "full", positions, None,
+                                    chunk)
+            with jax.named_scope("mlp"):
+                h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+                return x + L.mlp_apply(env, lp["mlp"], h2,
+                                       cfg.mlp_activation), None
         for i, kind in enumerate(cfg.pattern):
             x = apply_block_train(env, cfg, kind, lp[f"b{i}"], x,
                                   positions=positions, prefix_len=prefix_len,
@@ -359,13 +392,14 @@ def _run_stack_train(env, cfg, params, x, *, positions, prefix_len, run,
         return x, None
 
     body = _remat(body, run.remat_policy)
-    if stack is not None:
-        x, _ = jax.lax.scan(lambda c, lp: body(c, lp), x, stack)
-    for i, kind in enumerate(() if encoder else cfg.remainder_blocks):
-        x = apply_block_train(env, cfg, kind, params["rem"][i], x,
-                              positions=positions, prefix_len=prefix_len,
-                              chunk=chunk, enc_out=enc_out,
-                              enc_positions=enc_positions)
+    with jax.named_scope("layers"):
+        if stack is not None:
+            x, _ = jax.lax.scan(lambda c, lp: body(c, lp), x, stack)
+        for i, kind in enumerate(() if encoder else cfg.remainder_blocks):
+            x = apply_block_train(env, cfg, kind, params["rem"][i], x,
+                                  positions=positions, prefix_len=prefix_len,
+                                  chunk=chunk, enc_out=enc_out,
+                                  enc_positions=enc_positions)
     return x
 
 
@@ -383,18 +417,19 @@ def _run_stack_prefill(env, cfg, params, x, cache, *, positions, prefix_len,
                 enc_out=enc_out, enc_positions=enc_positions)
         return x, new_entries
 
-    if params.get("stack") is not None:
-        x, new_cache_stack = jax.lax.scan(
-            body, x, (params["stack"], cache["stack"]))
-    else:
-        new_cache_stack = cache.get("stack")
-    new_rem = []
-    for i, kind in enumerate(cfg.remainder_blocks):
-        x, entry = apply_block_prefill(
-            env, cfg, kind, params["rem"][i], x, cache["rem"][i],
-            positions=positions, prefix_len=prefix_len, chunk=chunk,
-            enc_out=enc_out, enc_positions=enc_positions)
-        new_rem.append(entry)
+    with jax.named_scope("layers"):
+        if params.get("stack") is not None:
+            x, new_cache_stack = jax.lax.scan(
+                body, x, (params["stack"], cache["stack"]))
+        else:
+            new_cache_stack = cache.get("stack")
+        new_rem = []
+        for i, kind in enumerate(cfg.remainder_blocks):
+            x, entry = apply_block_prefill(
+                env, cfg, kind, params["rem"][i], x, cache["rem"][i],
+                positions=positions, prefix_len=prefix_len, chunk=chunk,
+                enc_out=enc_out, enc_positions=enc_positions)
+            new_rem.append(entry)
     out_cache = {"stack": new_cache_stack}
     if new_rem:
         out_cache["rem"] = tuple(new_rem)
@@ -410,16 +445,18 @@ def _run_stack_decode(env, cfg, params, x_t, cache, *, pos):
                 env, cfg, kind, lp[f"b{i}"], x_t, lc[f"b{i}"], pos=pos)
         return x_t, new_entries
 
-    if params.get("stack") is not None:
-        x_t, new_cache_stack = jax.lax.scan(
-            body, x_t, (params["stack"], cache["stack"]))
-    else:
-        new_cache_stack = cache.get("stack")
-    new_rem = []
-    for i, kind in enumerate(cfg.remainder_blocks):
-        x_t, entry = apply_block_decode(
-            env, cfg, kind, params["rem"][i], x_t, cache["rem"][i], pos=pos)
-        new_rem.append(entry)
+    with jax.named_scope("layers"):
+        if params.get("stack") is not None:
+            x_t, new_cache_stack = jax.lax.scan(
+                body, x_t, (params["stack"], cache["stack"]))
+        else:
+            new_cache_stack = cache.get("stack")
+        new_rem = []
+        for i, kind in enumerate(cfg.remainder_blocks):
+            x_t, entry = apply_block_decode(
+                env, cfg, kind, params["rem"][i], x_t, cache["rem"][i],
+                pos=pos)
+            new_rem.append(entry)
     out_cache = {"stack": new_cache_stack}
     if new_rem:
         out_cache["rem"] = tuple(new_rem)
@@ -449,17 +486,26 @@ def _encode(env, cfg, params, batch, run):
     return L.rmsnorm(params["encoder"]["final_norm"], enc, cfg.norm_eps), pos
 
 
+def _embed(env, cfg, params, batch, run):
+    """The encoder's output where there is an encoder, and the embedded
+    inputs: (enc_out, enc_positions, x, positions, prefix_len)."""
+    with jax.named_scope("embed"):
+        enc_out = enc_pos = None
+        if cfg.is_encoder_decoder:
+            enc_out, enc_pos = _encode(env, cfg, params, batch, run)
+        return (enc_out, enc_pos) + _embed_inputs(env, cfg, params, batch)
+
+
 # ================================================================== public
 def forward_train(env: ShardEnv, cfg: ModelConfig, params, batch,
                   run: RunConfig):
-    enc_out = enc_pos = None
-    if cfg.is_encoder_decoder:
-        enc_out, enc_pos = _encode(env, cfg, params, batch, run)
-    x, positions, prefix_len = _embed_inputs(env, cfg, params, batch)
+    enc_out, enc_pos, x, positions, prefix_len = _embed(env, cfg, params,
+                                                        batch, run)
     x = _run_stack_train(env, cfg, params, x, positions=positions,
                          prefix_len=prefix_len, run=run,
                          enc_out=enc_out, enc_positions=enc_pos)
-    return L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("head"):
+        return L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
 
 def _logits(env, cfg, params, x):
@@ -477,27 +523,31 @@ def _ce(logits, targets, weights):
 
 def loss_fn(env: ShardEnv, cfg: ModelConfig, params, batch, run: RunConfig):
     x = forward_train(env, cfg, params, batch, run)
-    targets = batch["targets"]
-    if cfg.frontend == "vision":                   # loss over text suffix only
-        x = x[:, -targets.shape[1]:]
-    weights = (targets >= 0).astype(jnp.float32)
-    if run.loss_chunk and x.shape[1] % run.loss_chunk == 0 and \
-            x.shape[1] > run.loss_chunk:
-        nc = x.shape[1] // run.loss_chunk
-        xs = x.reshape(x.shape[0], nc, run.loss_chunk, x.shape[-1]).swapaxes(0, 1)
-        ts = targets.reshape(targets.shape[0], nc, run.loss_chunk).swapaxes(0, 1)
-        ws = weights.reshape(weights.shape[0], nc, run.loss_chunk).swapaxes(0, 1)
+    with jax.named_scope("head"):
+        targets = batch["targets"]
+        if cfg.frontend == "vision":           # loss over text suffix only
+            x = x[:, -targets.shape[1]:]
+        weights = (targets >= 0).astype(jnp.float32)
+        if run.loss_chunk and x.shape[1] % run.loss_chunk == 0 and \
+                x.shape[1] > run.loss_chunk:
+            nc = x.shape[1] // run.loss_chunk
+            xs = x.reshape(x.shape[0], nc, run.loss_chunk,
+                           x.shape[-1]).swapaxes(0, 1)
+            ts = targets.reshape(targets.shape[0], nc,
+                                 run.loss_chunk).swapaxes(0, 1)
+            ws = weights.reshape(weights.shape[0], nc,
+                                 run.loss_chunk).swapaxes(0, 1)
 
-        @jax.checkpoint
-        def chunk_loss(carry, xtw):
-            xc, tc, wc = xtw
-            n, d = _ce(_logits(env, cfg, params, xc), tc, wc)
-            return (carry[0] + n, carry[1] + d), None
+            @jax.checkpoint
+            def chunk_loss(carry, xtw):
+                xc, tc, wc = xtw
+                n, d = _ce(_logits(env, cfg, params, xc), tc, wc)
+                return (carry[0] + n, carry[1] + d), None
 
-        (num, den), _ = jax.lax.scan(chunk_loss, (0.0, 0.0), (xs, ts, ws))
-    else:
-        num, den = _ce(_logits(env, cfg, params, x), targets, weights)
-    return num / jnp.maximum(den, 1.0)
+            (num, den), _ = jax.lax.scan(chunk_loss, (0.0, 0.0), (xs, ts, ws))
+        else:
+            num, den = _ce(_logits(env, cfg, params, x), targets, weights)
+        return num / jnp.maximum(den, 1.0)
 
 
 # ==================================================================== cache
@@ -594,10 +644,8 @@ def cache_specs(cfg: ModelConfig):
 def prefill(env: ShardEnv, cfg: ModelConfig, params, batch, run: RunConfig,
             max_len: int = 0, kv_dtype=jnp.bfloat16):
     """Run the prompt, fill the cache, return (last_logits, cache, pos)."""
-    enc_out = enc_pos = None
-    if cfg.is_encoder_decoder:
-        enc_out, enc_pos = _encode(env, cfg, params, batch, run)
-    x, positions, prefix_len = _embed_inputs(env, cfg, params, batch)
+    enc_out, enc_pos, x, positions, prefix_len = _embed(env, cfg, params,
+                                                        batch, run)
     s = x.shape[1]
     b = x.shape[0]
     cache = init_cache(cfg, b, max(max_len or s, s),
@@ -607,8 +655,9 @@ def prefill(env: ShardEnv, cfg: ModelConfig, params, batch, run: RunConfig,
                                   positions=positions, prefix_len=prefix_len,
                                   run=run, enc_out=enc_out,
                                   enc_positions=enc_pos)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _logits(env, cfg, params, x[:, -1:])[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = _logits(env, cfg, params, x[:, -1:])[:, 0]
     pos = jnp.full((b,), s - 1, jnp.int32)
     return logits, cache, pos
 
@@ -617,11 +666,12 @@ def decode_step(env: ShardEnv, cfg: ModelConfig, params, token, pos, cache,
                 run: RunConfig):
     """One decode step. token: (B, 1) int32; pos: (B,) absolute position of
     the *new* token. Returns (logits (B, V), new_cache)."""
-    x = L.embed_lookup(env, params["embed"], token, cfg.embed_scale)
+    with jax.named_scope("embed"):
+        x = L.embed_lookup(env, params["embed"], token, cfg.embed_scale)
     x, cache = _run_stack_decode(env, cfg, params, x, cache, pos=pos)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _logits(env, cfg, params, x)[:, 0]
-    return logits, cache
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return _logits(env, cfg, params, x)[:, 0], cache
 
 
 # ============================================================== input_specs
