@@ -43,7 +43,9 @@ SCOPES = (
     "embed",       # token (and frontend or encoder) embedding
     "layers",      # the layer scan and the remainder blocks; what lies under
                    # it and under no block scope is the scan's own work (the
-                   # per-layer slicing and write-back of its xs and ys)
+                   # per-layer slicing of the stacked weights; in decode, the
+                   # per-layer read of the carried cache and the write of a
+                   # recurrent block's state into it)
     "attn_qkv",    # ln1, the q/k/v projections, rotary
     "kv_write",    # writing the new keys and values into the cache
     "attend",      # the attention itself (attention_core / decode_attend)
@@ -306,18 +308,25 @@ def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
     raise ValueError(kind)
 
 
-def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos):
-    """One-token step. x_t: (B, 1, d); pos: (B,) absolute position."""
+def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
+                       layer=None):
+    """One-token step. x_t: (B, 1, d); pos: (B,) absolute position.
+
+    With ``layer``, the leaves of ``cache_entry`` are stacked over the
+    layers and the block's own are those at ``layer``: the new key and
+    value are written in place at (layer, slot, position), and the
+    recurrent state is rewritten at ``layer``. Returns (x_t, new_entry)."""
     if kind in ATTN_BLOCKS:
         h, q, k, v = _qkv(env, cfg, bp, x_t, pos[:, None])
         ring = kind == BLOCK_LOCAL_ATTN
         new = dict(cache_entry)
         with jax.named_scope("kv_write"):
             new["k"], new["v"] = _decode_write_vec(
-                cache_entry["k"], cache_entry["v"], k, v, pos, ring)
+                cache_entry["k"], cache_entry["v"], k, v, pos, ring, layer)
+        cache_k, cache_v = (_at_layer(new[n], layer) for n in ("k", "v"))
         window = cfg.local_window if ring else 0
         with jax.named_scope("attend"):
-            o = attn.decode_attend(env, cfg, q, new["k"], new["v"], pos,
+            o = attn.decode_attend(env, cfg, q, cache_k, cache_v, pos,
                                    ring=ring, window=window)
         x_t = _attn_out(env, cfg, bp, x_t, o)
         if cfg.parallel_block:
@@ -325,39 +334,64 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos):
                 return x_t + L.mlp_apply(env, bp["mlp"], h,
                                          cfg.mlp_activation), new
         if cfg.is_encoder_decoder and "ck" in cache_entry:
+            cross_k, cross_v = (_at_layer(cache_entry[n], layer)
+                                for n in ("ck", "cv"))
             with jax.named_scope("cross_attn"):
                 hc = L.rmsnorm(bp["ln_cross"], x_t, cfg.norm_eps)
                 cq = jnp.einsum("bsd,dhk->bshk", hc, bp["cross"]["wq"])
                 if cfg.attn_bias:
                     cq = cq + bp["cross"]["bq"]
-                co = attn.decode_attend(env, cfg, cq, cache_entry["ck"],
-                                        cache_entry["cv"], pos, ring=False,
-                                        cross=True)
+                co = attn.decode_attend(env, cfg, cq, cross_k, cross_v, pos,
+                                        ring=False, cross=True)
                 x_t = x_t + attn.output_proj(env, cfg, bp["cross"], co)
         return _ffn_part(env, cfg, bp, x_t), new
+    if kind not in (BLOCK_RGLRU, BLOCK_SSD):
+        raise ValueError(kind)
+    state = tuple(_at_layer(cache_entry[n], layer) for n in ("h", "conv"))
     if kind == BLOCK_RGLRU:
         with jax.named_scope("rglru"):
             out, (h_new, conv) = rglru_mod.rglru_step(
                 env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
-                (cache_entry["h"], cache_entry["conv"]))
+                state)
             x_t = x_t + out
-        return _ffn_part(env, cfg, bp, x_t), {"h": h_new, "conv": conv}
-    if kind == BLOCK_SSD:
+        x_t = _ffn_part(env, cfg, bp, x_t)
+    else:
         with jax.named_scope("ssd"):
             out, (h_new, conv) = ssd_mod.ssd_step(
                 env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
-                (cache_entry["h"], cache_entry["conv"]))
-            return x_t + out, {"h": h_new, "conv": conv}
-    raise ValueError(kind)
+                state)
+            x_t = x_t + out
+    return x_t, {n: _set_layer(cache_entry[n], new, layer)
+                 for n, new in (("h", h_new), ("conv", conv))}
 
 
-def _decode_write_vec(cache_k, cache_v, k_t, v_t, pos, ring: bool):
-    """Per-sequence cache write. k_t: (B, 1, H, D); pos: (B,)."""
-    w = cache_k.shape[1]
+def _at_layer(leaf, layer):
+    """The block's own cache leaf: ``leaf`` at ``layer`` of a stacked cache,
+    or ``leaf`` itself where ``layer`` is None."""
+    if layer is None:
+        return leaf
+    return jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+
+
+def _set_layer(leaf, new, layer):
+    """``leaf`` with ``new`` in place of the block's own entry."""
+    if layer is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, layer, 0)
+
+
+def _decode_write_vec(cache_k, cache_v, k_t, v_t, pos, ring: bool,
+                      layer=None):
+    """Per-sequence cache write. k_t: (B, 1, H, D); pos: (B,). With
+    ``layer``, the caches are stacked over the layers and each token lands
+    at (layer, slot, position)."""
+    w = cache_k.shape[-3]
     slots = (pos % w) if ring else pos
-    b_idx = jnp.arange(cache_k.shape[0])
-    cache_k = cache_k.at[b_idx, slots].set(k_t[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[b_idx, slots].set(v_t[:, 0].astype(cache_v.dtype))
+    idx = (jnp.arange(k_t.shape[0]), slots)
+    if layer is not None:
+        idx = (layer,) + idx
+    cache_k = cache_k.at[idx].set(k_t[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[idx].set(v_t[:, 0].astype(cache_v.dtype))
     return cache_k, cache_v
 
 
@@ -437,20 +471,25 @@ def _run_stack_prefill(env, cfg, params, x, cache, *, positions, prefix_len,
 
 
 def _run_stack_decode(env, cfg, params, x_t, cache, *, pos):
-    def body(x_t, lp_lc):
-        lp, lc = lp_lc
-        new_entries = {}
+    """The decode step's layers. The stacked cache rides in the scan's carry
+    beside ``x_t``, and each block writes its layer of it in place; the
+    scan's xs are the stacked weights and the layer index."""
+    def body(carry, lp_layer):
+        x_t, stack = carry
+        lp, layer = lp_layer
+        stack = dict(stack)
         for i, kind in enumerate(cfg.pattern):
-            x_t, new_entries[f"b{i}"] = apply_block_decode(
-                env, cfg, kind, lp[f"b{i}"], x_t, lc[f"b{i}"], pos=pos)
-        return x_t, new_entries
+            x_t, stack[f"b{i}"] = apply_block_decode(
+                env, cfg, kind, lp[f"b{i}"], x_t, stack[f"b{i}"], pos=pos,
+                layer=layer)
+        return (x_t, stack), None
 
     with jax.named_scope("layers"):
+        new_cache_stack = cache.get("stack")
         if params.get("stack") is not None:
-            x_t, new_cache_stack = jax.lax.scan(
-                body, x_t, (params["stack"], cache["stack"]))
-        else:
-            new_cache_stack = cache.get("stack")
+            (x_t, new_cache_stack), _ = jax.lax.scan(
+                body, (x_t, new_cache_stack),
+                (params["stack"], jnp.arange(cfg.scan_repeats)))
         new_rem = []
         for i, kind in enumerate(cfg.remainder_blocks):
             x_t, entry = apply_block_decode(

@@ -6,7 +6,11 @@ not fit), at no chip time. Nothing runs, so nothing here checks a value.
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
+import json
 import os
+import pathlib
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,9 @@ from repro.models import model as M
 from repro.parallel.sharding import local_env
 
 HQ, HKV, D, CAP = 8, 4, 256, 50.0       # gemma2-2b attention widths
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
 
 
 @pytest.fixture(scope="module")
@@ -103,3 +110,33 @@ def test_gemma2_decode_step_compiles_for_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 5e9 < mem.argument_size_in_bytes and used < 16e9
+
+
+def test_stage_decode_step_writes_the_cache_in_place_for_v5e(one_chip):
+    """nemotron-4-15b's served stage (chipbench) at 48 slots of 2048, the
+    cache donated: the decode step keeps no second cache as a temporary,
+    and no copy or dynamic-update-slice writes the whole stacked cache."""
+    from chipbench.drivers.serve import model_config
+    cfg = model_config(json.loads(
+        (ROOT / "chipbench/configs/nemotron-4-15b-pp4stage.json").read_text()))
+    run = RunConfig(remat_policy="none", param_dtype="bfloat16")
+    env = local_env()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    cache = on_chip(M.cache_struct(cfg, 48, 2048))
+    tok = jax.ShapeDtypeStruct((48, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((48,), jnp.int32, sharding=one_chip)
+    c = jax.jit(lambda p, t, n, kv: M.decode_step(env, cfg, p, t, n, kv,
+                                                  run),
+                donate_argnums=3).lower(on_chip(M.param_shapes(cfg, run)),
+                                        tok, pos, cache).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 0.5e9
+    whole = {",".join(map(str, leaf.shape)) for leaf in jax.tree.leaves(cache)}
+    assert whole == {"8,48,2048,8,128"}
+    for line in c.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|dynamic-update-slice)\(", line)
+        assert not (m and m.group(1) in whole), line

@@ -132,3 +132,63 @@ def test_scopes_change_only_metadata(programs):
                 if ln.startswith(("x", "ENTRY", " ", "}"))]
     for program in ("decode", "prefill"):
         assert strip(scoped[program]) == strip(bare[program]), program
+
+
+def _instructions(text):
+    """(opcode, result dims, op_name) of every instruction that has an
+    array result."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', line)
+            yield (m.group(2), tuple(int(d) for d in m.group(1).split(",")
+                                     if d), name.group(1) if name else "")
+
+
+def _stacked_cache(text):
+    """{leaf key: dims} of the decode program's stacked cache arguments,
+    named by their path in the cache tree."""
+    leaves = {}
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* parameter\(\d+\).*op_name="
+                      r"\"kv\[\\'stack\\'\]\[\\'b\d+\\'\]\[\\'(\w+)\\'\]",
+                      line)
+        if m:
+            leaves.setdefault(m.group(2), set()).add(
+                tuple(int(d) for d in m.group(1).split(",")))
+    return leaves
+
+
+def test_decode_carries_the_cache_and_writes_it_in_place(programs):
+    """The decode program reads each layer's cache out of the stacked cache
+    under ``layers`` and no block scope, writes a new key and value at its
+    point in the stacked cache under ``kv_write``, rewrites a recurrent
+    state at its layer under ``layers``, and writes no whole layer of keys
+    or values back."""
+    _, scoped, _ = programs
+    text = scoped["decode"]
+    leaves = _stacked_cache(text)
+    assert leaves
+    ops = list(_instructions(text))
+
+    def scopes(name):
+        return [s for s in name.split("/")[:-1] if s in M.SCOPES]
+
+    for key, shapes in leaves.items():
+        for dims in shapes:
+            reads = [n for op, d, n in ops
+                     if op == "dynamic-slice" and d == (1,) + dims[1:]]
+            assert reads, (key, dims)
+            assert all(scopes(n) == ["layers"] for n in reads), (key, reads)
+            writes = [(op, n) for op, d, n in ops if d == dims and op in (
+                "scatter", "dynamic-update-slice")]
+            if key in ("k", "v"):
+                assert writes, key
+                assert all(op == "scatter" and scopes(n) == [
+                    "layers", "kv_write"] for op, n in writes), (key, writes)
+            elif key in ("h", "conv"):
+                assert writes, key
+                assert all(op == "dynamic-update-slice"
+                           and scopes(n) == ["layers"]
+                           for op, n in writes), (key, writes)
