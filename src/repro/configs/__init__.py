@@ -42,13 +42,14 @@ def get_config(name: str) -> ModelConfig:
         raise KeyError(f"unknown arch {name!r}; choose from {ARCH_NAMES}") from None
 
 
-def reduced_config(name: str) -> ModelConfig:
-    """Tiny same-family variant for CPU smoke tests.
+def reduced_config(name) -> ModelConfig:
+    """Tiny same-family variant for CPU smoke tests, of a registered
+    architecture's name or of a config.
 
     Keeps the block pattern *and* exercises the remainder-layer path when the
     full config has one (e.g. recurrentgemma's 38 = 3*12 + 2).
     """
-    cfg = get_config(name)
+    cfg = name if isinstance(name, ModelConfig) else get_config(name)
     pat = len(cfg.pattern)
     rem = cfg.num_layers % pat
     num_layers = 2 * pat + rem

@@ -53,6 +53,7 @@ class ModelConfig:
     final_logit_softcap: float = 0.0 # gemma2: tanh softcap on lm logits
     query_scale: float = 0.0         # 0 -> 1/sqrt(head_dim)
     rope_theta: float = 10000.0
+    use_rope: bool = True            # False: no positional encoding (NoPE)
     parallel_block: bool = False     # command-r: attn & ffn in parallel
     attn_bias: bool = False
 
@@ -67,6 +68,11 @@ class ModelConfig:
     # "ep": experts sharded over model axis (requires num_experts % tp == 0)
     # "tp": experts replicated, per-expert d_ff sharded over model axis
     moe_parallelism: str = "ep"
+    # The experts held here, a chip's share under expert parallelism: the
+    # router keeps all num_experts outputs, and the layer holds and computes
+    # experts [moe_held_start, moe_held_start + moe_held). 0 holds them all.
+    moe_held: int = 0
+    moe_held_start: int = 0
 
     # SSM / recurrent -------------------------------------------------------
     ssm_state_dim: int = 0           # Mamba2 N
@@ -75,6 +81,7 @@ class ModelConfig:
     ssm_chunk: int = 256             # SSD chunk length
     conv_width: int = 4              # depthwise conv width (mamba2 / rglru)
     rglru_width: int = 0             # RG-LRU recurrence width (0 -> d_model)
+    ssd_ffn: bool = False            # an FFN (ln2, MLP or MoE) after each SSD mixer
 
     # Encoder-decoder -------------------------------------------------------
     is_encoder_decoder: bool = False
@@ -88,6 +95,9 @@ class ModelConfig:
     # Embeddings ------------------------------------------------------------
     tie_embeddings: bool = True
     embed_scale: bool = True         # gemma-style sqrt(d_model) embed scaling
+    embed_multiplier: float = 1.0    # the embeddings times this (granite)
+    residual_multiplier: float = 1.0 # each block branch times this (granite)
+    logits_scaling: float = 1.0      # the logits divided by this (granite)
     norm_eps: float = 1e-6
 
     # Sharding / runtime overrides (merged over parallel/sharding.py defaults)
@@ -107,6 +117,8 @@ class ModelConfig:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if self.num_experts and self.num_experts_per_tok <= 0:
             raise ValueError("MoE config needs num_experts_per_tok > 0")
+        if self.moe_held_start + self.moe_held > self.num_experts:
+            raise ValueError("the experts held lie past num_experts")
 
     @property
     def q_per_kv(self) -> int:
@@ -154,7 +166,14 @@ class ModelConfig:
         o = self.num_heads * self.head_dim * d
         attn = qkv + o + (2 * self.head_dim if self.use_qk_norm else 0)
         mlp = d * ff * (3 if gated else 2)
-        per_block[BLOCK_GLOBAL_ATTN] = attn + 2 * d + (mlp + d if not self.num_experts else 0)
+        ffn = mlp
+        if self.num_experts:
+            e_ff = self.moe_d_ff or ff
+            held = self.moe_held or self.num_experts
+            ffn = held * d * e_ff * (3 if gated else 2) + d * self.num_experts
+            if self.moe_dense_residual:
+                ffn += mlp
+        per_block[BLOCK_GLOBAL_ATTN] = attn + 2 * d + ffn + (0 if self.num_experts else d)
         per_block[BLOCK_LOCAL_ATTN] = per_block[BLOCK_GLOBAL_ATTN]
         rw = self.rglru_width or d
         per_block[BLOCK_RGLRU] = (d * rw * 2 + rw * d + 3 * rw + rw * self.conv_width
@@ -163,13 +182,8 @@ class ModelConfig:
         nh = di // p if p else 0
         per_block[BLOCK_SSD] = (d * (2 * di + 2 * ns + nh) + di * d
                                 + (di + 2 * ns) * self.conv_width + 2 * nh + di + 2 * d)
-        if self.num_experts:
-            e_ff = self.moe_d_ff or ff
-            moe = self.num_experts * d * e_ff * (3 if gated else 2) + d * self.num_experts
-            if self.moe_dense_residual:
-                moe += d * ff * (3 if gated else 2)
-            per_block[BLOCK_GLOBAL_ATTN] += moe
-            per_block[BLOCK_LOCAL_ATTN] += moe
+        if self.ssd_ffn:
+            per_block[BLOCK_SSD] += d + ffn
         for kind, cnt in self.block_counts().items():
             n += cnt * per_block[kind]
         n += d                                        # final norm
@@ -187,9 +201,11 @@ class ModelConfig:
         gated = self.mlp_activation in ("swiglu", "geglu")
         e_ff = self.moe_d_ff or self.d_ff
         per_expert = d * e_ff * (3 if gated else 2)
-        inactive = (self.num_experts - self.num_experts_per_tok) * per_expert
-        n_attn_blocks = sum(c for k, c in self.block_counts().items() if k in ATTN_BLOCKS)
-        return self.param_count() - inactive * n_attn_blocks
+        held = self.moe_held or self.num_experts
+        inactive = (held - self.num_experts_per_tok * held // self.num_experts) * per_expert
+        n_moe_blocks = sum(c for k, c in self.block_counts().items()
+                           if k in ATTN_BLOCKS or (k == BLOCK_SSD and self.ssd_ffn))
+        return self.param_count() - inactive * n_moe_blocks
 
     def fingerprint(self) -> str:
         blob = json.dumps(dataclasses.asdict(self), sort_keys=True, default=str)

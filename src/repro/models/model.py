@@ -44,8 +44,8 @@ SCOPES = (
     "layers",      # the layer scan and the remainder blocks; what lies under
                    # it and under no block scope is the scan's own work (the
                    # per-layer slicing of the stacked weights; in decode, the
-                   # per-layer read of the carried cache and the write of a
-                   # recurrent block's state into it)
+                   # per-layer read of the carried cache and the write of an
+                   # RG-LRU block's state into it)
     "attn_qkv",    # ln1, the q/k/v projections, rotary
     "kv_write",    # writing the new keys and values into the cache
     "attend",      # the attention itself (attention_core / decode_attend)
@@ -55,10 +55,26 @@ SCOPES = (
     "rglru",       # ln1, the RG-LRU mixer and its residual
     "ssd",         # ln1, the SSD mixer and its residual
     "head",        # the final norm and the logits (and the loss)
+    "moe_route",   # in mlp: the router, its top-k and their weights
+    "moe_experts", # in mlp: the routed experts' matmuls (and dispatch)
+    "moe_shared",  # in mlp: the shared expert (the MLP beside the experts)
+    "ssd_state",   # in ssd: the state recurrence and its write into the
+                   # stacked cache (decode), or the chunked scan (prefill)
 )
 
 
 # ============================================================ block builders
+def _ffn_init(cfg: ModelConfig, ks, p, s, dtype):
+    """ln2 and the FFN after a block's mixer: the MLP, or the MoE and (with
+    ``moe_dense_residual``, a shared expert) the MLP beside it."""
+    p["ln2"], s["ln2"] = L.rmsnorm_init(cfg.d_model)
+    if cfg.num_experts:
+        p["moe"], s["moe"] = moe_mod.moe_init(cfg, ks[1], dtype)
+    if not cfg.num_experts or cfg.moe_dense_residual:
+        p["mlp"], s["mlp"] = L.mlp_init(
+            ks[2], cfg.d_model, cfg.d_ff, cfg.mlp_activation, dtype)
+
+
 def _block_init(cfg: ModelConfig, kind: str, key, dtype):
     ks = jax.random.split(key, 8)
     if kind in ATTN_BLOCKS:
@@ -66,15 +82,7 @@ def _block_init(cfg: ModelConfig, kind: str, key, dtype):
         s: Dict[str, Any] = {}
         p["ln1"], s["ln1"] = L.rmsnorm_init(cfg.d_model)
         p["attn"], s["attn"] = attn.attn_init(cfg, ks[0], dtype)
-        p["ln2"], s["ln2"] = L.rmsnorm_init(cfg.d_model)
-        if cfg.num_experts:
-            p["moe"], s["moe"] = moe_mod.moe_init(cfg, ks[1], dtype)
-            if cfg.moe_dense_residual:
-                p["mlp"], s["mlp"] = L.mlp_init(
-                    ks[2], cfg.d_model, cfg.d_ff, cfg.mlp_activation, dtype)
-        else:
-            p["mlp"], s["mlp"] = L.mlp_init(
-                ks[2], cfg.d_model, cfg.d_ff, cfg.mlp_activation, dtype)
+        _ffn_init(cfg, ks, p, s, dtype)
         if cfg.is_encoder_decoder:
             p["ln_cross"], s["ln_cross"] = L.rmsnorm_init(cfg.d_model)
             p["cross"], s["cross"] = attn.attn_init(cfg, ks[3], dtype, cross=True)
@@ -91,6 +99,8 @@ def _block_init(cfg: ModelConfig, kind: str, key, dtype):
         p, s = {}, {}
         p["ln1"], s["ln1"] = L.rmsnorm_init(cfg.d_model)
         p["ssd"], s["ssd"] = ssd_mod.ssd_init(cfg, ks[0], dtype)
+        if cfg.ssd_ffn:
+            _ffn_init(cfg, ks, p, s, dtype)
         return p, s
     raise ValueError(kind)
 
@@ -190,17 +200,24 @@ def _mask_kind(cfg, kind, prefix_len):
     return "causal"
 
 
+def _residual(cfg, x, y):
+    """x plus the block branch y, times the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def _qkv(env, cfg, bp, x, positions):
     with jax.named_scope("attn_qkv"):
         h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
         q, k, v = attn.project_qkv(env, cfg, bp["attn"], h,
-                                   positions=positions)
+                                   positions=positions, use_rope=cfg.use_rope)
     return h, q, k, v
 
 
 def _attn_out(env, cfg, bp, x, o):
     with jax.named_scope("attn_out"):
-        return x + attn.output_proj(env, cfg, bp["attn"], o)
+        return _residual(cfg, x, attn.output_proj(env, cfg, bp["attn"], o))
 
 
 def _self_attn(env, cfg, bp, x, mask, positions, prefix_len, chunk):
@@ -214,13 +231,15 @@ def _self_attn(env, cfg, bp, x, mask, positions, prefix_len, chunk):
 
 
 def _attn_tail(env, cfg, bp, x, h, *, positions, chunk, enc_out,
-               enc_positions):
+               enc_positions, serving=False):
     """What follows a block's self-attention over a whole sequence: the MLP
     beside it in a parallel block, else the cross-attention of an
-    encoder-decoder block and the MLP. Returns (x, (ck, cv) or None)."""
+    encoder-decoder block and the FFN (``_ffn_part``). Returns
+    (x, (ck, cv) or None)."""
     if cfg.parallel_block:
         with jax.named_scope("mlp"):
-            return x + L.mlp_apply(env, bp["mlp"], h, cfg.mlp_activation), None
+            return _residual(cfg, x, L.mlp_apply(env, bp["mlp"], h,
+                                                 cfg.mlp_activation)), None
     cross = None
     if cfg.is_encoder_decoder and enc_out is not None:
         with jax.named_scope("cross_attn"):
@@ -230,21 +249,31 @@ def _attn_tail(env, cfg, bp, x, h, *, positions, chunk, enc_out,
                 kv_positions=enc_positions, use_rope=False)
             co = attn.attention_core(env, cfg, cq, ck, cv, mask_kind="full",
                                      chunk=chunk)
-            x = x + attn.output_proj(env, cfg, bp["cross"], co)
+            x = _residual(cfg, x, attn.output_proj(env, cfg, bp["cross"], co))
         cross = (ck, cv)
-    return _ffn_part(env, cfg, bp, x), cross
+    return _ffn_part(env, cfg, bp, x, serving=serving)[0], cross
 
 
-def _ffn_part(env, cfg, bp, x):
+def _ffn_part(env, cfg, bp, x, serving=False):
+    """ln2, the FFN and its residual. Returns (x, counts). An expert layer
+    drops tokens past capacity in training; on the serving path
+    (``serving``) it drops none and counts the routed assignments that land
+    on each expert it holds (``moe.moe_serve``); elsewhere counts is None."""
+    counts = None
     with jax.named_scope("mlp"):
         h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
         if cfg.num_experts:
-            f = moe_mod.moe_apply(env, cfg, bp["moe"], h2)
+            if serving:
+                f, counts = moe_mod.moe_serve(cfg, bp["moe"], h2)
+            else:
+                f = moe_mod.moe_apply(env, cfg, bp["moe"], h2)
             if cfg.moe_dense_residual:
-                f = f + L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
+                with jax.named_scope("moe_shared"):
+                    f = f + L.mlp_apply(env, bp["mlp"], h2,
+                                        cfg.mlp_activation)
         else:
             f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
-        return x + f
+        return _residual(cfg, x, f), counts
 
 
 def apply_block_train(env, cfg, kind, bp, x, *, positions, prefix_len,
@@ -258,19 +287,21 @@ def apply_block_train(env, cfg, kind, bp, x, *, positions, prefix_len,
                           enc_positions=enc_positions)[0]
     if kind == BLOCK_RGLRU:
         with jax.named_scope("rglru"):
-            x = x + rglru_mod.rglru_forward(
-                env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
-        return _ffn_part(env, cfg, bp, x)
+            x = _residual(cfg, x, rglru_mod.rglru_forward(
+                env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps)))
+        return _ffn_part(env, cfg, bp, x)[0]
     if kind == BLOCK_SSD:
         with jax.named_scope("ssd"):
-            return x + ssd_mod.ssd_forward(
-                env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps))
+            x = _residual(cfg, x, ssd_mod.ssd_forward(
+                env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps)))
+        return _ffn_part(env, cfg, bp, x)[0] if cfg.ssd_ffn else x
     raise ValueError(kind)
 
 
 def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
                         prefix_len, chunk, enc_out=None, enc_positions=None):
-    """Like train, but fills ``cache_entry`` and returns (x, new_entry)."""
+    """Like train, but fills ``cache_entry`` and returns (x, new_entry). An
+    expert layer takes the serving path (``_ffn_part``)."""
     if kind in ATTN_BLOCKS:
         x, h, k, v = _self_attn(env, cfg, bp, x,
                                 _mask_kind(cfg, kind, prefix_len),
@@ -286,7 +317,7 @@ def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
                     cache_entry["k"], cache_entry["v"], k, v, 0)
         x, cross = _attn_tail(env, cfg, bp, x, h, positions=positions,
                               chunk=chunk, enc_out=enc_out,
-                              enc_positions=enc_positions)
+                              enc_positions=enc_positions, serving=True)
         if cross is not None:
             with jax.named_scope("kv_write"):
                 new["ck"], new["cv"] = (c.astype(new[n].dtype)
@@ -297,14 +328,18 @@ def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
             out, (h_last, conv) = rglru_mod.rglru_forward(
                 env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
                 return_state=True)
-            x = x + out
-        return _ffn_part(env, cfg, bp, x), {"h": h_last, "conv": conv}
+            x = _residual(cfg, x, out)
+        return (_ffn_part(env, cfg, bp, x, serving=True)[0],
+                {"h": h_last, "conv": conv})
     if kind == BLOCK_SSD:
         with jax.named_scope("ssd"):
             out, (h_last, conv) = ssd_mod.ssd_forward(
                 env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x, cfg.norm_eps),
                 return_state=True)
-            return x + out, {"h": h_last, "conv": conv}
+            x = _residual(cfg, x, out)
+        if cfg.ssd_ffn:
+            x = _ffn_part(env, cfg, bp, x, serving=True)[0]
+        return x, {"h": h_last, "conv": conv}
     raise ValueError(kind)
 
 
@@ -315,7 +350,9 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
     With ``layer``, the leaves of ``cache_entry`` are stacked over the
     layers and the block's own are those at ``layer``: the new key and
     value are written in place at (layer, slot, position), and the
-    recurrent state is rewritten at ``layer``. Returns (x_t, new_entry)."""
+    recurrent state is rewritten at ``layer`` (an SSD's under
+    ``ssd_state``). Returns (x_t, new_entry, counts): an expert layer's
+    counts of the serving path (``_ffn_part``), else None."""
     if kind in ATTN_BLOCKS:
         h, q, k, v = _qkv(env, cfg, bp, x_t, pos[:, None])
         ring = kind == BLOCK_LOCAL_ATTN
@@ -331,8 +368,8 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
         x_t = _attn_out(env, cfg, bp, x_t, o)
         if cfg.parallel_block:
             with jax.named_scope("mlp"):
-                return x_t + L.mlp_apply(env, bp["mlp"], h,
-                                         cfg.mlp_activation), new
+                return _residual(cfg, x_t, L.mlp_apply(
+                    env, bp["mlp"], h, cfg.mlp_activation)), new, None
         if cfg.is_encoder_decoder and "ck" in cache_entry:
             cross_k, cross_v = (_at_layer(cache_entry[n], layer)
                                 for n in ("ck", "cv"))
@@ -343,26 +380,36 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
                     cq = cq + bp["cross"]["bq"]
                 co = attn.decode_attend(env, cfg, cq, cross_k, cross_v, pos,
                                         ring=False, cross=True)
-                x_t = x_t + attn.output_proj(env, cfg, bp["cross"], co)
-        return _ffn_part(env, cfg, bp, x_t), new
+                x_t = _residual(cfg, x_t,
+                                attn.output_proj(env, cfg, bp["cross"], co))
+        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True)
+        return x_t, new, counts
     if kind not in (BLOCK_RGLRU, BLOCK_SSD):
         raise ValueError(kind)
     state = tuple(_at_layer(cache_entry[n], layer) for n in ("h", "conv"))
+
+    def write(h_new, conv):
+        return {n: _set_layer(cache_entry[n], new, layer)
+                for n, new in (("h", h_new), ("conv", conv))}
     if kind == BLOCK_RGLRU:
         with jax.named_scope("rglru"):
-            out, (h_new, conv) = rglru_mod.rglru_step(
+            out, new_state = rglru_mod.rglru_step(
                 env, cfg, bp["rglru"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
                 state)
-            x_t = x_t + out
-        x_t = _ffn_part(env, cfg, bp, x_t)
+            x_t = _residual(cfg, x_t, out)
+        new = write(*new_state)
     else:
         with jax.named_scope("ssd"):
-            out, (h_new, conv) = ssd_mod.ssd_step(
+            out, new_state = ssd_mod.ssd_step(
                 env, cfg, bp["ssd"], L.rmsnorm(bp["ln1"], x_t, cfg.norm_eps),
                 state)
-            x_t = x_t + out
-    return x_t, {n: _set_layer(cache_entry[n], new, layer)
-                 for n, new in (("h", h_new), ("conv", conv))}
+            x_t = _residual(cfg, x_t, out)
+            with jax.named_scope("ssd_state"):
+                new = write(*new_state)
+    counts = None
+    if kind == BLOCK_RGLRU or cfg.ssd_ffn:
+        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True)
+    return x_t, new, counts
 
 
 def _at_layer(leaf, layer):
@@ -473,40 +520,62 @@ def _run_stack_prefill(env, cfg, params, x, cache, *, positions, prefix_len,
 def _run_stack_decode(env, cfg, params, x_t, cache, *, pos):
     """The decode step's layers. The stacked cache rides in the scan's carry
     beside ``x_t``, and each block writes its layer of it in place; the
-    scan's xs are the stacked weights and the layer index."""
+    scan's xs are the stacked weights and the layer index. Returns
+    (x_t, cache, counts): counts, for a model with experts, the routed
+    assignments on each held expert of each layer, (num_layers, held)
+    int32, else None."""
+    def stacked(counts):
+        counts = [n for n in counts if n is not None]
+        return jnp.stack(counts) if counts else None
+
     def body(carry, lp_layer):
         x_t, stack = carry
         lp, layer = lp_layer
         stack = dict(stack)
+        counts = []
         for i, kind in enumerate(cfg.pattern):
-            x_t, stack[f"b{i}"] = apply_block_decode(
+            x_t, stack[f"b{i}"], n = apply_block_decode(
                 env, cfg, kind, lp[f"b{i}"], x_t, stack[f"b{i}"], pos=pos,
                 layer=layer)
-        return (x_t, stack), None
+            counts.append(n)
+        return (x_t, stack), stacked(counts)
 
+    counts = []
     with jax.named_scope("layers"):
         new_cache_stack = cache.get("stack")
         if params.get("stack") is not None:
-            (x_t, new_cache_stack), _ = jax.lax.scan(
+            (x_t, new_cache_stack), per_rep = jax.lax.scan(
                 body, (x_t, new_cache_stack),
                 (params["stack"], jnp.arange(cfg.scan_repeats)))
-        new_rem = []
+            if per_rep is not None:
+                counts.append(per_rep.reshape(-1, per_rep.shape[-1]))
+        new_rem, rem_counts = [], []
         for i, kind in enumerate(cfg.remainder_blocks):
-            x_t, entry = apply_block_decode(
+            x_t, entry, n = apply_block_decode(
                 env, cfg, kind, params["rem"][i], x_t, cache["rem"][i],
                 pos=pos)
             new_rem.append(entry)
+            rem_counts.append(n)
+        counts.append(stacked(rem_counts))
     out_cache = {"stack": new_cache_stack}
     if new_rem:
         out_cache["rem"] = tuple(new_rem)
-    return x_t, out_cache
+    counts = [n for n in counts if n is not None]
+    return x_t, out_cache, (jnp.concatenate(counts) if counts else None)
 
 
 # ============================================================== embeddings
+def _embed_tokens(env, cfg, params, tokens):
+    x = L.embed_lookup(env, params["embed"], tokens, cfg.embed_scale)
+    if cfg.embed_multiplier != 1.0:
+        x = x * cfg.embed_multiplier
+    return x
+
+
 def _embed_inputs(env, cfg, params, batch):
     """Token (+frontend) embedding. Returns (x, positions, prefix_len)."""
     tokens = batch["tokens"]
-    x = L.embed_lookup(env, params["embed"], tokens, cfg.embed_scale)
+    x = _embed_tokens(env, cfg, params, tokens)
     prefix_len = None
     if cfg.frontend == "vision":
         patches = batch["patch_embeds"].astype(x.dtype)
@@ -548,8 +617,12 @@ def forward_train(env: ShardEnv, cfg: ModelConfig, params, batch,
 
 
 def _logits(env, cfg, params, x):
-    return L.unembed(env, params["embed"], x, cfg.tie_embeddings,
-                     head=params.get("lm_head"), cap=cfg.final_logit_softcap)
+    logits = L.unembed(env, params["embed"], x, cfg.tie_embeddings,
+                       head=params.get("lm_head"),
+                       cap=cfg.final_logit_softcap)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _ce(logits, targets, weights):
@@ -702,15 +775,18 @@ def prefill(env: ShardEnv, cfg: ModelConfig, params, batch, run: RunConfig,
 
 
 def decode_step(env: ShardEnv, cfg: ModelConfig, params, token, pos, cache,
-                run: RunConfig):
+                run: RunConfig, expert_counts: bool = False):
     """One decode step. token: (B, 1) int32; pos: (B,) absolute position of
-    the *new* token. Returns (logits (B, V), new_cache)."""
+    the *new* token. Returns (logits (B, V), new_cache), and with
+    ``expert_counts`` for a model with experts the routed assignments that
+    land on each held expert of each layer, (num_layers, held) int32."""
     with jax.named_scope("embed"):
-        x = L.embed_lookup(env, params["embed"], token, cfg.embed_scale)
-    x, cache = _run_stack_decode(env, cfg, params, x, cache, pos=pos)
+        x = _embed_tokens(env, cfg, params, token)
+    x, cache, counts = _run_stack_decode(env, cfg, params, x, cache, pos=pos)
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return _logits(env, cfg, params, x)[:, 0], cache
+        logits = _logits(env, cfg, params, x)[:, 0]
+    return (logits, cache, counts) if expert_counts else (logits, cache)
 
 
 # ============================================================== input_specs

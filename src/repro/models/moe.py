@@ -18,8 +18,16 @@ the TPU-native adaptation of megablocks-style grouped matmul:
   per-expert d_ff sharded over ``model``; the same body runs with
   ``E_local == E`` and psum combining ff-shard partials.
 
-Tokens beyond an expert's capacity are dropped (GShard semantics); tests
-use a high capacity factor and compare against the dense oracle.
+Tokens beyond an expert's capacity are dropped (GShard semantics) in
+training; tests use a high capacity factor and compare against the dense
+oracle. The serving path (``moe_serve``) drops none: it runs every held
+expert on every token and weights each output by the routing, as the dense
+oracle does.
+
+A config with ``moe_held`` holds a share of the experts (a chip's under
+expert parallelism): the router keeps all ``num_experts`` outputs and picks
+the top k of them, and the layer computes only the held experts' part of
+the result; what the other experts would add is left out.
 """
 from __future__ import annotations
 
@@ -37,12 +45,12 @@ BIG = jnp.iinfo(jnp.int32).max
 
 
 def moe_init(cfg, key, dtype):
-    d, e = cfg.d_model, cfg.num_experts
+    d, e = cfg.d_model, cfg.moe_held or cfg.num_experts
     ff = cfg.moe_d_ff or cfg.d_ff
     ks = jax.random.split(key, 4)
     gated = cfg.mlp_activation in GATED
     p = {
-        "router": nd_init(ks[0], (d, e), d, jnp.float32),
+        "router": nd_init(ks[0], (d, cfg.num_experts), d, jnp.float32),
         "w_in": nd_init(ks[1], (e, d, ff), d, dtype),
         "w_out": nd_init(ks[2], (e, ff, d), ff, dtype),
     }
@@ -114,19 +122,27 @@ def _dispatch_compute(x, ids, combine, w_in, w_gate, w_out, *,
     return out.reshape(bl, s, d)
 
 
+def _route(cfg, params, x):
+    """The router's top-k experts of each token and their weights, a softmax
+    over the k chosen logits: (gate_w, ids), each (B, S, k)."""
+    logits = (x.astype(jnp.float32) @ params["router"])
+    gate_w, ids = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+    return jax.nn.softmax(gate_w, axis=-1), ids
+
+
 def moe_apply(env, cfg, params, x, *, capacity_factor: float = 2.0):
     """x: (B, S, d) -> (B, S, d). Router in fp32 outside shard_map."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = (x.astype(jnp.float32) @ params["router"])
-    gate_w, ids = jax.lax.top_k(logits, k)                     # (B,S,k)
-    gate_w = jax.nn.softmax(gate_w, axis=-1)
+    held = cfg.moe_held or e
+    with jax.named_scope("moe_route"):
+        gate_w, ids = _route(cfg, params, x)                   # (B,S,k)
 
     dp = env.dp
     t_local = (b * s) // dp
     ep = cfg.moe_parallelism == "ep"
     tp = env.tp
-    e_local = e // tp if ep else e
+    e_local = held // tp if ep else held
     capacity = max(8, int(capacity_factor * t_local * k / e))
     capacity = min(capacity, t_local * k)
 
@@ -144,10 +160,9 @@ def moe_apply(env, cfg, params, x, *, capacity_factor: float = 2.0):
                          and env.rules.get("p_embed") == "data") else ""
 
     def body(x_l, ids_l, wgt_l, w_in, w_gate, w_out):
+        e0 = cfg.moe_held_start
         if ep and model_ax:
-            e0 = jax.lax.axis_index(model_ax) * e_local
-        else:
-            e0 = 0
+            e0 = e0 + jax.lax.axis_index(model_ax) * e_local
         out = _dispatch_compute(
             x_l, ids_l, wgt_l, w_in, w_gate, w_out,
             activation=cfg.mlp_activation, capacity=capacity,
@@ -161,34 +176,47 @@ def moe_apply(env, cfg, params, x, *, capacity_factor: float = 2.0):
     in_specs = [x_spec, id_spec, id_spec, w_spec,
                 gate_spec if w_gate is not None else P(), w2_spec]
     out_spec = env.pspec("act_batch", None, None)
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=out_spec, check_rep=False)
-    if w_gate is None:
-        w_gate_arg = jnp.zeros((), x.dtype)  # placeholder, unused
+    with jax.named_scope("moe_experts"):
+        if w_gate is None:
+            w_gate = jnp.zeros((), x.dtype)  # placeholder, unused
 
-        def body_nogate(x_l, i_l, g_l, wi, _pl, wo):
-            return body(x_l, i_l, g_l, wi, None, wo)
-        fn = shard_map(body_nogate, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=out_spec, check_rep=False)
-        return fn(x, ids, gate_w, params["w_in"], w_gate_arg, params["w_out"])
-    return fn(x, ids, gate_w, params["w_in"], w_gate, params["w_out"])
+            def body_nogate(x_l, i_l, g_l, wi, _pl, wo):
+                return body(x_l, i_l, g_l, wi, None, wo)
+            fn = shard_map(body_nogate, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_spec, check_rep=False)
+        else:
+            fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_spec, check_rep=False)
+        return fn(x, ids, gate_w, params["w_in"], w_gate, params["w_out"])
+
+
+def moe_serve(cfg, params, x):
+    """The serving path: every held expert on every token, each expert's
+    activations weighted by its routing weight (0 where the token was not
+    routed to it) before the output projection, so no token is dropped.
+    Returns (out, counts): out (B, S, d), and counts (held,) int32, the
+    routed assignments that land on each held expert."""
+    held = cfg.moe_held or cfg.num_experts
+    with jax.named_scope("moe_route"):
+        gate_w, ids = _route(cfg, params, x)                   # (B,S,k)
+        routed = jax.nn.one_hot(ids - cfg.moe_held_start, held,
+                                dtype=jnp.float32)             # (B,S,k,E)
+        w = jnp.einsum("bske,bsk->bse", routed, gate_w)
+        counts = routed.sum((0, 1, 2)).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        h = jnp.einsum("bsd,edf->bsef", x, params["w_in"],
+                       preferred_element_type=jnp.float32)
+        g = (jnp.einsum("bsd,edf->bsef", x, params["w_gate"],
+                        preferred_element_type=jnp.float32)
+             if "w_gate" in params else None)
+        h = (mlp_activate(cfg.mlp_activation, h, g)
+             * w[..., None]).astype(x.dtype)
+        out = jnp.einsum("bsef,efd->bsd", h, params["w_out"],
+                         preferred_element_type=jnp.float32)
+    return out.astype(x.dtype), counts
 
 
 def moe_ref(cfg, params, x):
-    """Dense oracle: run every expert on every token, mask by routing.
-    No capacity limit — matches moe_apply when nothing is dropped."""
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = x.astype(jnp.float32) @ params["router"]
-    gate_w, ids = jax.lax.top_k(logits, k)
-    gate_w = jax.nn.softmax(gate_w, axis=-1)
-    h = jnp.einsum("bsd,edf->bsef", x, params["w_in"],
-                   preferred_element_type=jnp.float32)
-    g = (jnp.einsum("bsd,edf->bsef", x, params["w_gate"],
-                    preferred_element_type=jnp.float32)
-         if "w_gate" in params else None)
-    h = mlp_activate(cfg.mlp_activation, h, g).astype(x.dtype)
-    y = jnp.einsum("bsef,efd->bsed", h, params["w_out"],
-                   preferred_element_type=jnp.float32)
-    mask = jax.nn.one_hot(ids, e, dtype=jnp.float32) * gate_w[..., None]
-    w_per_expert = mask.sum(axis=2)                            # (B,S,E)
-    return jnp.einsum("bsed,bse->bsd", y, w_per_expert).astype(x.dtype)
+    """Dense oracle: ``moe_serve``'s output. No capacity limit — matches
+    moe_apply when nothing is dropped."""
+    return moe_serve(cfg, params, x)[0]

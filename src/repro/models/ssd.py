@@ -84,47 +84,48 @@ def ssd_forward(env, cfg, params, x, *, state=None, conv_state=None,
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,S,nh)
     da = -jnp.exp(params["a_log"]) * dt                               # (B,S,nh) <= 0
 
-    # chunk views
-    xs_c = xs.reshape(bsz, nc, q, nh, p_dim).astype(jnp.float32)
-    b_c = bmat.reshape(bsz, nc, q, n).astype(jnp.float32)
-    c_c = cmat.reshape(bsz, nc, q, n).astype(jnp.float32)
-    da_c = da.reshape(bsz, nc, q, nh)
-    dt_c = dt.reshape(bsz, nc, q, nh)
+    with jax.named_scope("ssd_state"):
+        # chunk views
+        xs_c = xs.reshape(bsz, nc, q, nh, p_dim).astype(jnp.float32)
+        b_c = bmat.reshape(bsz, nc, q, n).astype(jnp.float32)
+        c_c = cmat.reshape(bsz, nc, q, n).astype(jnp.float32)
+        da_c = da.reshape(bsz, nc, q, nh)
+        dt_c = dt.reshape(bsz, nc, q, nh)
 
-    cum = jnp.cumsum(da_c, axis=2)                                    # (B,nc,q,nh)
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]               # (B,nc,q,q,nh)
-    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None]
-    # mask BEFORE exp: on causal entries seg <= 0, so exp never overflows;
-    # masking after exp produces inf * 0 = NaN in the backward pass.
-    l_mat = jnp.exp(jnp.where(causal, seg, -1e30))
+        cum = jnp.cumsum(da_c, axis=2)                                    # (B,nc,q,nh)
+        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]               # (B,nc,q,q,nh)
+        causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None]
+        # mask BEFORE exp: on causal entries seg <= 0, so exp never overflows;
+        # masking after exp produces inf * 0 = NaN in the backward pass.
+        l_mat = jnp.exp(jnp.where(causal, seg, -1e30))
 
-    # intra-chunk: Y = (C B^T ⊙ L ⊙ dt_j) X
-    cb = jnp.einsum("bcin,bcjn->bcij", c_c, b_c)                      # (B,nc,q,q)
-    att = cb[..., None] * l_mat * dt_c[:, :, None, :, :]              # (B,nc,q,q,nh)
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att, xs_c)
+        # intra-chunk: Y = (C B^T ⊙ L ⊙ dt_j) X
+        cb = jnp.einsum("bcin,bcjn->bcij", c_c, b_c)                      # (B,nc,q,q)
+        att = cb[..., None] * l_mat * dt_c[:, :, None, :, :]              # (B,nc,q,q,nh)
+        y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att, xs_c)
 
-    # state to carry: S_c = sum_j exp(cum_Q - cum_j) dt_j B_j x_j^T
-    decay_end = jnp.exp(cum[:, :, -1:, :] - cum)                      # (B,nc,q,nh)
-    sin = jnp.einsum("bcjh,bcjn,bcjhp->bchpn",
-                     decay_end * dt_c, b_c, xs_c)                     # (B,nc,nh,P,N)
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                           # (B,nc,nh)
+        # state to carry: S_c = sum_j exp(cum_Q - cum_j) dt_j B_j x_j^T
+        decay_end = jnp.exp(cum[:, :, -1:, :] - cum)                      # (B,nc,q,nh)
+        sin = jnp.einsum("bcjh,bcjn,bcjhp->bchpn",
+                         decay_end * dt_c, b_c, xs_c)                     # (B,nc,nh,P,N)
+        chunk_decay = jnp.exp(cum[:, :, -1, :])                           # (B,nc,nh)
 
-    def chunk_step(h, inp):
-        s_in, dec, c_blk, cum_blk = inp
-        # inter-chunk contribution: y_i += C_i exp(cum_i) h_prev
-        y_inter = jnp.einsum("bin,bih,bhpn->bihp",
-                             c_blk, jnp.exp(cum_blk), h)
-        h_new = dec[:, :, None, None] * h + s_in
-        return h_new, y_inter
+        def chunk_step(h, inp):
+            s_in, dec, c_blk, cum_blk = inp
+            # inter-chunk contribution: y_i += C_i exp(cum_i) h_prev
+            y_inter = jnp.einsum("bin,bih,bhpn->bihp",
+                                 c_blk, jnp.exp(cum_blk), h)
+            h_new = dec[:, :, None, None] * h + s_in
+            return h_new, y_inter
 
-    if state is None:
-        state = jnp.zeros((bsz, nh, p_dim, n), jnp.float32)
-    h_last, y_inter = jax.lax.scan(
-        chunk_step, state,
-        (sin.swapaxes(0, 1), chunk_decay.swapaxes(0, 1),
-         c_c.swapaxes(0, 1), cum.swapaxes(0, 1)))
-    y = y_intra + y_inter.swapaxes(0, 1)                              # (B,nc,q,nh,P)
-    y = y.reshape(bsz, s, nh, p_dim)
+        if state is None:
+            state = jnp.zeros((bsz, nh, p_dim, n), jnp.float32)
+        h_last, y_inter = jax.lax.scan(
+            chunk_step, state,
+            (sin.swapaxes(0, 1), chunk_decay.swapaxes(0, 1),
+             c_c.swapaxes(0, 1), cum.swapaxes(0, 1)))
+        y = y_intra + y_inter.swapaxes(0, 1)                              # (B,nc,q,nh,P)
+        y = y.reshape(bsz, s, nh, p_dim)
     y = y + params["d_skip"][:, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, s, di)
     y = _gated_norm(params, y, z, cfg.norm_eps).astype(x.dtype)
@@ -148,9 +149,10 @@ def ssd_step(env, cfg, params, x_t, state_tuple):
     cvec = xbc_c[..., di + n:].astype(jnp.float32)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     da = jnp.exp(-jnp.exp(params["a_log"]) * dt)                      # (B,nh)
-    h_new = (da[:, :, None, None] * h
-             + jnp.einsum("bh,bn,bhp->bhpn", dt, bvec, xs))
-    y = jnp.einsum("bn,bhpn->bhp", cvec, h_new)
+    with jax.named_scope("ssd_state"):
+        h_new = (da[:, :, None, None] * h
+                 + jnp.einsum("bh,bn,bhp->bhpn", dt, bvec, xs))
+        y = jnp.einsum("bn,bhpn->bhp", cvec, h_new)
     y = y + params["d_skip"][:, None] * xs
     y = y.reshape(-1, di)
     y = _gated_norm(params, y, z, cfg.norm_eps).astype(x_t.dtype)

@@ -140,3 +140,40 @@ def test_stage_decode_step_writes_the_cache_in_place_for_v5e(one_chip):
         m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
                      r"(copy|dynamic-update-slice)\(", line)
         assert not (m and m.group(1) in whole), line
+
+
+def test_granite_stage_decode_and_longest_prefill_fit_v5e(one_chip):
+    """granite-4.0-h-small's served stage (chipbench) at 96 slots of 4096:
+    the decode step, its cache donated, keeps no second cache as a
+    temporary (the Mamba-2 state is rewritten in place at its layer), and
+    the weights, the cache and the 1792-token prefill's temporaries fit one
+    16 GB chip together."""
+    from chipbench.drivers import serve_hybrid
+    cfg = serve_hybrid.model_config(json.loads(
+        (ROOT / "chipbench/configs/granite-4.0-h-small-pp4ep4.json")
+        .read_text()))
+    run = RunConfig(remat_policy="none", param_dtype="bfloat16")
+    fns = serve_hybrid.programs(cfg, run, 4096, jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(M.param_shapes(cfg, run))
+    cache = on_chip(M.cache_struct(cfg, 96, 4096))
+    dec = fns["decode"].lower(
+        params, jax.ShapeDtypeStruct((96, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((96,), jnp.int32, sharding=one_chip),
+        cache).compile().memory_analysis()
+    pre = fns["prefill"].lower(
+        params, jax.ShapeDtypeStruct((1, 1792), jnp.int32,
+                                     sharding=one_chip)).compile()
+    pre = pre.memory_analysis()
+    # 2.96 B parameters in bf16 and a 5.32 GB cache
+    assert 11.2e9 < dec.argument_size_in_bytes < 11.3e9
+    assert dec.temp_size_in_bytes < 0.5e9
+    # the whole cache, the donated argument, is the output's buffer
+    assert dec.alias_size_in_bytes == sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert dec.argument_size_in_bytes + pre.temp_size_in_bytes \
+        + pre.output_size_in_bytes < 14e9

@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.configs.base import RunConfig, ShapeConfig
+from repro.configs.granite_4_0_h_small import CONFIG as GRANITE_H
 from repro.models import model as M
 from repro.parallel.sharding import local_env
 
@@ -20,8 +21,10 @@ CASES = {
     "dense": ("nemotron-4-15b", ATTENTION),
     "local-attention": ("gemma2-2b", ATTENTION),
     "rglru": ("recurrentgemma-9b", ATTENTION | {"rglru"}),
-    "ssd": ("mamba2-2.7b", {"embed", "layers", "ssd", "head"}),
+    "ssd": ("mamba2-2.7b", {"embed", "layers", "ssd", "ssd_state", "head"}),
     "cross-attention": ("seamless-m4t-medium", ATTENTION | {"cross_attn"}),
+    "hybrid-moe": (GRANITE_H, ATTENTION | {"ssd", "ssd_state", "moe_route",
+                                           "moe_experts", "moe_shared"}),
 }
 
 
@@ -164,9 +167,10 @@ def test_decode_carries_the_cache_and_writes_it_in_place(programs):
     """The decode program reads each layer's cache out of the stacked cache
     under ``layers`` and no block scope, writes a new key and value at its
     point in the stacked cache under ``kv_write``, rewrites a recurrent
-    state at its layer under ``layers``, and writes no whole layer of keys
-    or values back."""
-    _, scoped, _ = programs
+    state at its layer under ``layers`` (an SSD's under ``ssd_state``, with
+    the state update fused into the write), and writes no whole layer of
+    keys or values back."""
+    expected, scoped, _ = programs
     text = scoped["decode"]
     leaves = _stacked_cache(text)
     assert leaves
@@ -189,6 +193,8 @@ def test_decode_carries_the_cache_and_writes_it_in_place(programs):
                     "layers", "kv_write"] for op, n in writes), (key, writes)
             elif key in ("h", "conv"):
                 assert writes, key
+                state = (["layers", "ssd", "ssd_state"] if "ssd" in expected
+                         else ["layers"])
                 assert all(op == "dynamic-update-slice"
-                           and scopes(n) == ["layers"]
+                           and scopes(n) == state
                            for op, n in writes), (key, writes)
