@@ -1,4 +1,5 @@
-"""jit'd wrapper: GQA decode attention against a (B, S, Hkv, D) cache."""
+"""jit'd wrapper: GQA decode attention against a KV cache, or against one
+layer of a cache stacked over the layers, read in place."""
 from __future__ import annotations
 
 import functools
@@ -7,35 +8,34 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attn.kernel import decode_attention_kernel
+from repro.kernels.decode_attn.kernel import (decode_attention_kernel,
+                                             head_group)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "softcap", "scale", "block_kv", "interpret"))
-def decode_attention(q, cache_k, cache_v, lengths, *, softcap: float = 0.0,
-                     scale: float = 0.0, block_kv: int = 256,
-                     interpret: bool = False):
-    """q: (B, 1, Hq, D); cache_k/v: (B, S, Hkv, D); lengths: (B,) number of
-    valid cache positions per sequence. Returns (B, 1, Hq, D)."""
+def decode_attention(q, cache_k, cache_v, lengths, *, layer=None,
+                     softcap: float = 0.0, scale: float = 0.0,
+                     block_kv: int = 512, interpret: bool = False):
+    """q: (B, 1, Hq, D); lengths: (B,) number of valid cache positions per
+    sequence. Without ``layer``, cache_k/v are (B, S, Hkv, D); with it,
+    (L, B, S, Hkv, D), and the attention reads layer ``layer`` of them in
+    place. With heads of 128, a 16-bit cache holds one KV head or an even
+    number of them. Returns (B, 1, Hq, D)."""
+    if layer is None:
+        cache_k, cache_v, layer = cache_k[None], cache_v[None], 0
     b, _, hq, d = q.shape
-    s, hkv = cache_k.shape[1], cache_k.shape[2]
-    g = hq // hkv
-    scale = scale or 1.0 / math.sqrt(d)
-
-    k = jnp.repeat(cache_k, g, axis=2).transpose(0, 2, 1, 3).reshape(
-        b * hq, s, d)
-    v = jnp.repeat(cache_v, g, axis=2).transpose(0, 2, 1, 3).reshape(
-        b * hq, s, d)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * hq, 1, d)
-    lens = jnp.repeat(lengths, hq).astype(jnp.int32)
-
-    bkv = min(block_kv, s)
-    pad = (-s) % bkv
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-
-    out = decode_attention_kernel(qf, k, v, lens, scale=scale,
-                                  softcap=softcap, block_kv=bkv,
-                                  interpret=interpret)
-    return out.reshape(b, hq, 1, d).transpose(0, 2, 1, 3)
+    s, hkv = cache_k.shape[2], cache_k.shape[3]
+    group = head_group(cache_k.dtype, hkv, d)
+    if hkv % group:
+        raise ValueError(f"decode_attention: {hkv} KV heads of "
+                         f"{cache_k.dtype} do not split into groups of "
+                         f"{group}")
+    out = decode_attention_kernel(
+        q.reshape(b, hkv // group, hq // hkv * group, d).astype(
+            cache_k.dtype),
+        cache_k, cache_v, lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        scale=scale or 1.0 / math.sqrt(d), softcap=softcap,
+        block_kv=min(block_kv, s), interpret=interpret)
+    return out.reshape(b, 1, hq, d).astype(q.dtype)

@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs.base import (
     ATTN_BLOCKS, BLOCK_GLOBAL_ATTN, BLOCK_LOCAL_ATTN, BLOCK_RGLRU, BLOCK_SSD,
     ModelConfig, RunConfig, ShapeConfig)
+from repro.kernels.decode_attn.ops import decode_attention
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import moe as moe_mod
@@ -44,11 +45,13 @@ SCOPES = (
     "layers",      # the layer scan and the remainder blocks; what lies under
                    # it and under no block scope is the scan's own work (the
                    # per-layer slicing of the stacked weights; in decode, the
-                   # per-layer read of the carried cache and the write of an
-                   # RG-LRU block's state into it)
+                   # per-layer read of the carried cache where no kernel
+                   # reads it in place, and the write of an RG-LRU block's
+                   # state into it)
     "attn_qkv",    # ln1, the q/k/v projections, rotary
     "kv_write",    # writing the new keys and values into the cache
-    "attend",      # the attention itself (attention_core / decode_attend)
+    "attend",      # the attention itself (attention_core / decode_attend,
+                   # or the decode_attn kernel on the carried cache)
     "attn_out",    # the output projection and its residual
     "cross_attn",  # the cross-attention of an encoder-decoder block
     "mlp",         # ln2, the MLP or MoE, and its residual
@@ -349,10 +352,12 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
 
     With ``layer``, the leaves of ``cache_entry`` are stacked over the
     layers and the block's own are those at ``layer``: the new key and
-    value are written in place at (layer, slot, position), and the
-    recurrent state is rewritten at ``layer`` (an SSD's under
-    ``ssd_state``). Returns (x_t, new_entry, counts): an expert layer's
-    counts of the serving path (``_ffn_part``), else None."""
+    value are written in place at (layer, slot, position) and, on one TPU
+    chip, a full-length cache's self-attention reads its layer there
+    (``_in_place_attention``); the recurrent state is rewritten at
+    ``layer`` (an SSD's under ``ssd_state``). Returns (x_t, new_entry,
+    counts): an expert layer's counts of the serving path (``_ffn_part``),
+    else None."""
     if kind in ATTN_BLOCKS:
         h, q, k, v = _qkv(env, cfg, bp, x_t, pos[:, None])
         ring = kind == BLOCK_LOCAL_ATTN
@@ -360,11 +365,18 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
         with jax.named_scope("kv_write"):
             new["k"], new["v"] = _decode_write_vec(
                 cache_entry["k"], cache_entry["v"], k, v, pos, ring, layer)
-        cache_k, cache_v = (_at_layer(new[n], layer) for n in ("k", "v"))
-        window = cfg.local_window if ring else 0
+        in_place = None if ring or layer is None else _in_place_attention(env)
+        if in_place is None:
+            cache_k, cache_v = (_at_layer(new[n], layer) for n in ("k", "v"))
         with jax.named_scope("attend"):
-            o = attn.decode_attend(env, cfg, q, cache_k, cache_v, pos,
-                                   ring=ring, window=window)
+            if in_place is not None:
+                o = in_place(q, new["k"], new["v"], pos + 1, layer=layer,
+                             softcap=cfg.attn_logit_softcap,
+                             scale=attn._scale(cfg))
+            else:
+                o = attn.decode_attend(
+                    env, cfg, q, cache_k, cache_v, pos, ring=ring,
+                    window=cfg.local_window if ring else 0)
         x_t = _attn_out(env, cfg, bp, x_t, o)
         if cfg.parallel_block:
             with jax.named_scope("mlp"):
@@ -410,6 +422,17 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
     if kind == BLOCK_RGLRU or cfg.ssd_ffn:
         x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True)
     return x_t, new, counts
+
+
+def _in_place_attention(env):
+    """The ``decode_attn`` kernel where a stacked decode step's
+    self-attention over a full-length cache may read its layer of the
+    carried cache in place, and only up to each slot's length: on one TPU
+    chip (a custom call is not partitioned over a mesh). Elsewhere None:
+    the layer's keys and values are sliced out for ``attn.decode_attend``."""
+    if env.mesh.size != 1 or env.mesh.devices.flat[0].platform != "tpu":
+        return None
+    return decode_attention
 
 
 def _at_layer(leaf, layer):

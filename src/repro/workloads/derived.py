@@ -23,10 +23,11 @@ line, so a Pallas block of ``b`` rows is ``b`` consecutive lines.
   its Q tile is re-read every KV step (private reuse — SWS-like), while
   warps of the same head stream the *same* K/V tiles (shared lines with
   skewed overlap: late q-rows touch many more tiles than early ones).
-* ``decodeattn`` — :mod:`repro.kernels.decode_attn.kernel`: grid
-  ``(BH, num_kv_blocks)``; one warp per head; the single q row is hot,
-  the per-head KV cache streams once (LWS-like), and per-sequence
-  ``lengths`` skew makes long-context heads the heavy interferers.
+* ``decodeattn`` — :mod:`repro.kernels.decode_attn.kernel`: one warp
+  per (sequence, head) KV stream of its ``(batch, num_kv_blocks)`` grid;
+  the q row is hot, each head's KV streams once (LWS-like) up to its
+  sequence's length, and ``lengths`` skew makes long-context heads the
+  heavy interferers.
 * ``gather`` — :mod:`repro.kernels.ciao_gather.ref.cache_sim_ref`'s
   index stream: per-stream (= per-warp) gathers into one shared table;
   most streams walk strided windows with re-reference, a few *irregular*

@@ -142,6 +142,45 @@ def test_stage_decode_step_writes_the_cache_in_place_for_v5e(one_chip):
         assert not (m and m.group(1) in whole), line
 
 
+def test_stage_decode_step_reads_each_layer_in_place_for_v5e(one_chip,
+                                                            monkeypatch):
+    """nemotron-4-15b's served stage at 48 slots of 2048 with the
+    ``decode_attn`` kernel, as the model takes it on one chip: the kernel
+    is in the program; no instruction outside it has one layer's keys or
+    values as its result; and no copy or dynamic-update-slice writes the
+    whole stacked cache, so nothing shields the kernel's read from the
+    next layer's in-place write."""
+    from chipbench.drivers.serve import model_config
+    monkeypatch.setattr(M, "_in_place_attention", lambda env: decode_attention)
+    cfg = model_config(json.loads(
+        (ROOT / "chipbench/configs/nemotron-4-15b-pp4stage.json").read_text()))
+    run = RunConfig(remat_policy="none", param_dtype="bfloat16")
+    env = local_env()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    cache = on_chip(M.cache_struct(cfg, 48, 2048))
+    tok = jax.ShapeDtypeStruct((48, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((48,), jnp.int32, sharding=one_chip)
+    c = jax.jit(lambda p, t, n, kv: M.decode_step(env, cfg, p, t, n, kv,
+                                                  run),
+                donate_argnums=3).lower(on_chip(M.param_shapes(cfg, run)),
+                                        tok, pos, cache).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert c.memory_analysis().temp_size_in_bytes < 0.5e9
+    one_layer = {"1,48,2048,8,128", "48,2048,8,128"}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m:
+            assert m.group(1) not in one_layer, line
+            assert not (m.group(1) == "8,48,2048,8,128" and m.group(2) in (
+                "copy", "copy-start", "dynamic-update-slice")), line
+
+
 def test_granite_stage_decode_and_longest_prefill_fit_v5e(one_chip):
     """granite-4.0-h-small's served stage (chipbench) at 96 slots of 4096:
     the decode step, its cache donated, keeps no second cache as a

@@ -1,13 +1,17 @@
 """Decode steps over a batch of sequences at different positions: every
 family's logits match the full forward at float32, step after step, and each
 step leaves every cache position it does not write bit for bit as it was."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import reduced_config
+from repro.configs import granite_4_0_h_small
 from repro.configs.base import RunConfig
+from repro.kernels.decode_attn.ops import decode_attention
 from repro.models import model as M
 from repro.parallel.sharding import local_env
 
@@ -87,3 +91,45 @@ def test_ragged_decode_steps_match_the_full_forward(family):
             logits, full[np.arange(len(PROMPTS)), pos], atol=2e-2)
         _unwritten_unchanged(cache, new, pos)
         cache, pos = new, pos + 1
+
+
+@pytest.mark.parametrize("name", ["nemotron-4-15b", "granite-4.0-h-small"])
+def test_stacked_decode_reads_the_cache_in_place_like_decode_attend(
+        name, monkeypatch):
+    """The stacked decode step with the ``decode_attn`` kernel (interpret
+    mode, as on one TPU chip) gives the logits and the cache of the path
+    that slices each layer's keys and values out for ``decode_attend``,
+    step after step, for a dense config and a hybrid with an attention
+    layer."""
+    cfg = reduced_config(granite_4_0_h_small.CONFIG
+                         if name == "granite-4.0-h-small" else name)
+    run = RunConfig(remat_policy="none", param_dtype="float32")
+    key = jax.random.PRNGKey(0)
+    params = M.init_params(cfg, key, run)
+    total = max(PROMPTS) + STEPS
+    tokens = jax.random.randint(key, (len(PROMPTS), total), 0,
+                                cfg.vocab_size)
+    cache = _batched([M.prefill(ENV, cfg, params,
+                                {"tokens": tokens[b:b + 1, :n]}, run,
+                                max_len=total, kv_dtype=jnp.float32)[1]
+                      for b, n in enumerate(PROMPTS)])
+
+    def steps():
+        kv, pos, out = cache, np.array(PROMPTS), []
+        step = jax.jit(lambda t, n, c: M.decode_step(ENV, cfg, params, t, n,
+                                                     c, run))
+        for _ in range(STEPS):
+            token = tokens[np.arange(len(PROMPTS)), pos][:, None]
+            logits, kv = step(token, jnp.asarray(pos, jnp.int32), kv)
+            out.append((logits, kv))
+            pos = pos + 1
+        return out
+
+    want = steps()
+    monkeypatch.setattr(M, "_in_place_attention", lambda env: partial(
+        decode_attention, interpret=True))
+    got = steps()
+    for (lg, kv), (lw, kw) in zip(got, want):
+        np.testing.assert_allclose(lg, lw, atol=1e-5)
+        for a, b in zip(jax.tree.leaves(kv), jax.tree.leaves(kw)):
+            np.testing.assert_allclose(a, b, atol=1e-5)

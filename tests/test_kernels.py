@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles (interpret=True), sweeping shapes and
 dtypes as required for each kernel."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,8 @@ from repro.kernels.decode_attn.ops import decode_attention
 from repro.kernels.decode_attn.ref import decode_ref
 from repro.kernels.ciao_gather.ops import ciao_gather
 from repro.kernels.ciao_gather.ref import cache_sim_ref, gather_ref
+from repro.models.attention import decode_attend
+from repro.parallel.sharding import local_env
 
 
 def _fold(q, k, v):
@@ -66,6 +70,39 @@ def test_decode_attention_vs_oracle(b, s, hq, hkv, d, dtype, atol):
                      vb.astype(jnp.float32), jnp.repeat(lens, hq))
     ref = ref.reshape(b, hq, 1, d).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(out.astype(jnp.float32), ref, atol=atol)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("cap", [0.0, 50.0])
+@pytest.mark.parametrize("hq,hkv", [(48, 8), (32, 8)])   # nemotron, granite
+def test_decode_attention_reads_a_stacked_cache_in_place(hq, hkv, cap, dtype,
+                                                         atol):
+    """One layer of a stacked cache, read in place, against
+    ``attention.decode_attend`` on that layer alone. Every other layer and
+    every position past a slot's length hold NaN: a finite, matching output
+    shows that the kernel reads its own layer and nothing past a length."""
+    layers, layer, bkv, d = 3, 1, 128, 128
+    lens = np.array([1, bkv, bkv + 1, 3 * bkv], np.int32)
+    b, s = len(lens), int(lens.max())
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (b, 1, hq, d), dtype)
+    k = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (b, s, hkv, d), dtype)
+    live = (np.arange(s)[None, :] < lens[:, None])[:, :, None, None]
+    stack_k, stack_v = (jnp.full((layers,) + x.shape, jnp.nan, dtype).at[
+        layer].set(jnp.where(live, x, jnp.nan)) for x in (k, v))
+    out = decode_attention(q, stack_k, stack_v, jnp.asarray(lens),
+                           layer=jnp.int32(layer), softcap=cap, block_kv=bkv,
+                           interpret=True)
+    cfg = types.SimpleNamespace(head_dim=d, query_scale=0.0,
+                                attn_logit_softcap=cap)
+    clean_k, clean_v = (jnp.where(live, x, 0) for x in (k, v))
+    ref = decode_attend(local_env(), cfg, q, clean_k, clean_v,
+                        jnp.asarray(lens - 1), ring=False)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
