@@ -15,6 +15,7 @@ drawn arguments and nothing more.
 """
 from __future__ import annotations
 
+import inspect
 import random
 import sys
 import types
@@ -74,8 +75,13 @@ def settings(max_examples: int = _DEFAULT_MAX_EXAMPLES, **_kw):
 
 def given(*strategies: SearchStrategy):
     def decorate(fn):
-        # *args-only signature so pytest doesn't mistake the strategy
-        # parameters for fixtures.
+        # like hypothesis, positional strategies fill the rightmost
+        # parameters; the wrapper's signature shows pytest only the rest
+        # (fixtures and parametrize arguments).
+        params = list(inspect.signature(fn).parameters.values())
+        n_free = len(params) - len(strategies)
+        drawn_names = [p.name for p in params[n_free:]]
+
         def wrapper(*args, **kwargs):
             n = getattr(wrapper, "_stub_max_examples",
                         getattr(fn, "_stub_max_examples",
@@ -85,7 +91,7 @@ def given(*strategies: SearchStrategy):
             for _ in range(n):
                 drawn = tuple(s.example_from(rng) for s in strategies)
                 try:
-                    fn(*args, *drawn, **kwargs)
+                    fn(*args, **kwargs, **dict(zip(drawn_names, drawn)))
                 except Exception as exc:
                     raise AssertionError(
                         f"{fn.__name__} failed on drawn arguments "
@@ -95,6 +101,7 @@ def given(*strategies: SearchStrategy):
         wrapper.__doc__ = fn.__doc__
         wrapper.__module__ = fn.__module__
         wrapper.__dict__.update(fn.__dict__)
+        wrapper.__signature__ = inspect.Signature(params[:n_free])
         return wrapper
     return decorate
 

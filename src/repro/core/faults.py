@@ -20,20 +20,9 @@ Sites are plain strings fired by the code under test via
   (``runner.save_records``, ``ledger.RunLedger.save_chunk``).
 * ``cell.run``       — per scalar (per-cell) execution, both the
   spawn-pool path and the batched engine's final fallback rung.
-* ``lease.claim``    — per chunk-lease claim attempt in cooperative
-  multi-worker runs (``ledger.RunLedger.claim_lease``); ``path`` is
-  the lease file.
-* ``lease.heartbeat`` — per lease heartbeat
-  (``ledger.RunLedger.heartbeat_lease``, fired from the worker's
-  ``LeaseKeeper`` thread); with ``runs work``'s fatal handler a
-  ``raise`` here kills the worker mid-chunk — the canonical
-  "crashed holder" chaos clause.
 * ``chunk.resplit``  — when a chunk blows its ``chunk_budget_s`` and
   is about to be split into child chunks (``runner``); a ``raise``
   models dying before the resplit record is published.
-* ``worker.exit``    — immediately after a successful lease claim in
-  the cooperative chunk path (``runner``); a ``raise`` deterministically
-  simulates a worker dying while holding a lease.
 * ``serve.admit``    — per admission attempt in the serving engine
   (``serving.engine.ServeEngine._admit``); key is the candidate rid.
 * ``serve.preempt``  — per preemption decision
@@ -91,8 +80,8 @@ import time
 from typing import List, Optional
 
 SITES = ("chunk.dispatch", "stepper.step", "cache.load", "records.save",
-         "cell.run", "lease.claim", "lease.heartbeat", "chunk.resplit",
-         "worker.exit", "serve.admit", "serve.preempt", "serve.page_alloc")
+         "cell.run", "chunk.resplit", "serve.admit", "serve.preempt",
+         "serve.page_alloc")
 ACTIONS = ("raise", "corrupt", "delay")
 
 

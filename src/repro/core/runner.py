@@ -466,20 +466,6 @@ def _failed_cell(cell: _Cell, exc: BaseException, attempts: int,
         attempts=attempts, backends=list(trail), truncated=truncated)
 
 
-@dataclasses.dataclass
-class _Coop:
-    """Cooperative multi-worker execution state (``run_grid(...,
-    coordinate=True)``): this worker's identity, the chunk-lease TTL,
-    the heartbeat keeper thread, the poll cadence for chunks leased to
-    other workers, and the shared lease counters merged into
-    :func:`last_batched_perf` at the end of the run."""
-    worker: str
-    ttl: float
-    keeper: Any
-    poll_s: float
-    stats: Dict[str, float]
-
-
 def _cell_fault_key(cell: _Cell) -> str:
     return f"{cell.workload}/{cell.policy}/{cell.variant}"
 
@@ -493,7 +479,6 @@ def _run_cells_batched(cells: Sequence[_Cell],
                        run_ledger=None,
                        gidx: Optional[Sequence[int]] = None,
                        chunk_budget: Optional[float] = None,
-                       coop: Optional[_Coop] = None,
                        ) -> Tuple[List[AnyRecord], Dict[str, float]]:
     """Run batchable cells through the lockstep engine: flatten Best-SWL
     / statPCAL limit sweeps into per-limit subcells, group by (SimConfig,
@@ -533,15 +518,10 @@ def _run_cells_batched(cells: Sequence[_Cell],
     ``chunk_budget`` bounds each *chunk's* wall clock (seconds, not an
     absolute time like ``deadline``): a chunk that blows its budget is
     not truncated but **re-sharded** — split at cell boundaries into
-    child chunks (recorded in the ledger's ``resplits/`` so resumed or
-    cooperating workers adopt the same plan) that re-enter the queue,
-    so chronically slow chunks converge to single cells instead of
-    starving the run. Uses the same bounded-cycle quantum slicing as
-    ``deadline``. ``coop`` (built by ``run_grid(coordinate=True)``)
-    makes chunk execution lease-based: each chunk is claimed in the
-    ledger before running, heartbeated while running, and released
-    after its shard lands; chunks leased to other live workers are
-    polled until their shard appears or their lease expires (takeover).
+    child chunks (recorded in the ledger's ``resplits/`` so a resumed
+    run adopts the same plan) that re-enter the queue, so chronically
+    slow chunks converge to single cells instead of starving the run.
+    Uses the same bounded-cycle quantum slicing as ``deadline``.
     """
     import time as _time
 
@@ -624,11 +604,10 @@ def _run_cells_batched(cells: Sequence[_Cell],
     fault_keys = [_fkey_of(chunk) for _, _, chunk in chunks]
     local_of = {g: i for i, g in enumerate(gidx)}
 
-    # adopt recorded budget resplits: chunks a previous (or concurrent)
-    # worker split are replaced by the same children, so every worker's
-    # plan converges on identical content-addressed keys. Child item
-    # order is canonical (sorted ids) so duplicate executions write
-    # byte-identical shards.
+    # adopt recorded budget resplits: chunks a previous run split are
+    # replaced by the same children, so the resumed plan converges on
+    # identical content-addressed keys. Child item order is canonical
+    # (sorted ids) so a re-executed child writes a byte-identical shard.
     if run_ledger is not None:
         saved = run_ledger.load_resplits()
         examine = collections.deque(range(len(chunks))) if saved else ()
@@ -694,7 +673,7 @@ def _run_cells_batched(cells: Sequence[_Cell],
         boundaries for a single sweep cell; ``None`` for a single item
         (nothing smaller to converge to). Children use canonical
         (sorted-id) item order, matching the plan-time reapplication
-        above, so duplicate executions write byte-identical shards."""
+        above, so a re-executed child writes a byte-identical shard."""
         cell_is = sorted({i for i, _, _ in chunk})
         if len(cell_is) >= 2:
             head = set(cell_is[:len(cell_is) // 2])
@@ -797,31 +776,7 @@ def _run_cells_batched(cells: Sequence[_Cell],
         cell_is = sorted({i for i, _, _ in chunk})
         if deadline is not None and _time.monotonic() >= deadline:
             return ("truncated", cell_is, 0, [])
-        lease = None
-        if coop is not None:
-            lease = run_ledger.claim_lease(chunk_keys[n], coop.worker,
-                                           coop.ttl)
-            if lease is None:
-                coop.stats["lease_conflicts"] += 1
-                return ("leased", n)
-            coop.stats["lease_claims"] += 1
-            if lease.get("takeover_of"):
-                coop.stats["lease_takeovers"] += 1
-            # deterministic crash site: a `raise` here dies holding the
-            # lease — exactly what a SIGKILLed worker leaves behind
-            faults.fire("worker.exit", key=fault_keys[n])
-            coop.keeper.add(chunk_keys[n], lease)
-        try:
-            out = _exec_chunk(n, cfg, gpu, chunk, cell_is)
-        finally:
-            if lease is not None:
-                coop.keeper.remove(chunk_keys[n])
-        if lease is not None:
-            # released on *any* tagged outcome (the shard — when one was
-            # earned — is already on disk); an exception above skips
-            # this, leaving the lease to expire like a real crash
-            run_ledger.release_lease(chunk_keys[n], lease)
-        return out
+        return _exec_chunk(n, cfg, gpu, chunk, cell_is)
 
     chunks_mu = threading.Lock()
 
@@ -838,16 +793,12 @@ def _run_cells_batched(cells: Sequence[_Cell],
         return new
 
     outs: List[Tuple] = []
-    waiting: List[int] = []   # chunks leased to other live workers
 
     def _collect(out) -> List[int]:
         """Main-thread result triage; returns chunk indices to
         (re)queue — a resplit chunk's children."""
         if out[0] == "resplit":
             return _register_children(out[1], out[2])
-        if out[0] == "leased":
-            waiting.append(out[1])
-            return []
         outs.append(out)
         return []
 
@@ -867,34 +818,6 @@ def _run_cells_batched(cells: Sequence[_Cell],
         queue = collections.deque(order)
         while queue:
             queue.extend(_collect(_run_chunk(queue.popleft())))
-
-    # cooperative wait loop: poll chunks leased to other workers until
-    # their shard lands (resumed), their lease expires (takeover — the
-    # claim inside _run_chunk succeeds), or the deadline passes
-    while waiting:
-        if deadline is not None and _time.monotonic() >= deadline:
-            for n in waiting:
-                outs.append(("truncated",
-                             sorted({i for i, _, _ in chunks[n][2]}),
-                             0, []))
-            waiting = []
-            break
-        progressed = False
-        queue = collections.deque(waiting)
-        waiting = []
-        while queue:
-            out = _run_chunk(queue.popleft())
-            if out[0] == "leased":
-                waiting.append(out[1])
-            elif out[0] == "resplit":
-                queue.extend(_register_children(out[1], out[2]))
-                progressed = True
-            else:
-                outs.append(out)
-                progressed = True
-        if waiting and not progressed:
-            coop.stats["lease_wait_s"] += coop.poll_s
-            _time.sleep(coop.poll_s)
 
     results: Dict[int, List] = {}
     rec_map: Dict[int, RunRecord] = {}
@@ -1049,11 +972,7 @@ def run_grid(grid: ExperimentGrid, processes: Optional[int] = None,
              deadline_s: Optional[float] = None,
              run_id: Optional[str] = None,
              resume: Optional[str] = None,
-             chunk_budget_s: Optional[float] = None,
-             coordinate: bool = False,
-             lease_ttl_s: Optional[float] = None,
-             worker: Optional[str] = None,
-             heartbeat_fatal: bool = False) -> List[AnyRecord]:
+             chunk_budget_s: Optional[float] = None) -> List[AnyRecord]:
     """Run every cell; see the module docstring for the three engines.
     ``jobs`` (preferred name; ``processes`` is the legacy alias) sets
     the parallelism: the batched engine fans chunks over that many
@@ -1082,21 +1001,11 @@ def run_grid(grid: ExperimentGrid, processes: Optional[int] = None,
       id (a crash flight recorder).
     * ``chunk_budget_s`` bounds each chunk's wall clock: a chunk that
       exceeds it is **re-sharded** at cell boundaries into child chunks
-      that re-enter the queue (and are recorded in the ledger so
-      resumes/co-workers adopt the same plan) — stragglers converge to
-      single cells instead of starving the run or being truncated.
-    * ``coordinate=True`` (requires ``run_id``/``resume``) makes this
-      process one of N cooperating workers draining the same run:
-      chunks are claimed via ledger leases (TTL ``lease_ttl_s``,
-      default ``$REPRO_LEASE_TTL`` or 30s), heartbeated while running,
-      and reclaimed from crashed workers once their lease expires.
-      Records stay bit-identical to a serial run regardless of worker
-      count, crashes, or duplicate completions (see the ledger module
-      docstring). ``worker`` names this worker (default
-      ``<hostname>-<pid>``); ``heartbeat_fatal=True`` (the
-      ``python -m repro.runs work`` entrypoint sets it) turns a failed
-      or stolen heartbeat into immediate worker death (exit 70) so a
-      wedged worker cannot double-spend a reclaimed chunk's time.
+      that re-enter the queue (and are recorded in the ledger so a
+      resume adopts the same plan) — stragglers converge to single
+      cells instead of starving the run or being truncated.
+
+    One process drives a run ledger at a time.
     """
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}; one of {ENGINES}")
@@ -1115,62 +1024,30 @@ def run_grid(grid: ExperimentGrid, processes: Optional[int] = None,
     ghash = _ledger.grid_hash(grid)
     if run_id is None and os.environ.get("REPRO_RUN_LEDGER", ""):
         run_id = _auto_run_id(grid, ghash)
-    if coordinate and run_id is None:
-        raise ValueError("coordinate=True requires run_id= or resume= "
-                         "— cooperating workers meet at a ledger")
     led = None
     if run_id is not None:
         led = _ledger.RunLedger(run_id)
-        # cooperating workers must never wipe each other's shards: a
-        # coordinate open of an existing run always resumes it
         led.open({"grid_hash": ghash, "grid": _grid_meta(grid),
                   "grid_doc": grid_to_doc(grid),
                   "engine": engine, "jobs": jobs, "strict": strict,
                   "cells": len(expand_grid(grid))},
-                 resume=(resume is not None
-                         or (coordinate and led.manifest_path.exists())))
-    coop = None
-    if coordinate:
-        ttl = (float(lease_ttl_s) if lease_ttl_s is not None
-               else _ledger.lease_ttl())
-        wid = worker or _ledger.worker_id()
-        on_fatal = None
-        if heartbeat_fatal:
-            def on_fatal(reason: str) -> None:
-                import sys
-                print(f"# worker {wid}: fatal: {reason}",
-                      file=sys.stderr, flush=True)
-                os._exit(70)
-        keeper = _ledger.LeaseKeeper(led, ttl, on_fatal=on_fatal)
-        keeper.start()
-        coop = _Coop(worker=wid, ttl=ttl, keeper=keeper,
-                     poll_s=min(max(ttl / 4.0, 0.05), 1.0),
-                     stats=dict(lease_claims=0.0, lease_conflicts=0.0,
-                                lease_takeovers=0.0, lease_wait_s=0.0))
+                 resume=resume is not None)
     deadline = (time.monotonic() + deadline_s
                 if deadline_s is not None else None)
     cells = expand_grid(grid)
     records: List[Optional[AnyRecord]] = [None] * len(cells)
-    batched_ran = False
     if engine != "process":
         batch_idx = [i for i, c in enumerate(cells) if _batchable(c)]
         if engine in ("batched", "jax") \
                 or len(batch_idx) >= AUTO_MIN_BATCH:
-            try:
-                recs, perf = _run_cells_batched(
-                    [cells[i] for i in batch_idx],
-                    backend="jax" if engine == "jax" else None,
-                    workers=batch_workers(jobs),
-                    strict=strict, retries=retries, deadline=deadline,
-                    run_ledger=led, gidx=batch_idx,
-                    chunk_budget=chunk_budget_s, coop=coop)
-            except BaseException:
-                # a strict-mode fault must not leak the heartbeat thread
-                if coop is not None:
-                    coop.keeper.stop()
-                raise
+            recs, perf = _run_cells_batched(
+                [cells[i] for i in batch_idx],
+                backend="jax" if engine == "jax" else None,
+                workers=batch_workers(jobs),
+                strict=strict, retries=retries, deadline=deadline,
+                run_ledger=led, gidx=batch_idx,
+                chunk_budget=chunk_budget_s)
             _TLS.batched_perf = perf
-            batched_ran = True
             for i, rec in zip(batch_idx, recs):
                 records[i] = rec
     rest = [i for i in range(len(cells)) if records[i] is None]
@@ -1185,13 +1062,6 @@ def run_grid(grid: ExperimentGrid, processes: Optional[int] = None,
             else:
                 still.append(i)
         rest = still
-    if rest and coop is not None:
-        try:
-            rest = _run_rest_coop(cells, rest, records, led, coop,
-                                  deadline, strict)
-        except BaseException:
-            coop.keeper.stop()
-            raise
     if rest and deadline is not None and time.monotonic() >= deadline:
         for i in rest:
             records[i] = _failed_cell(
@@ -1211,15 +1081,6 @@ def run_grid(grid: ExperimentGrid, processes: Optional[int] = None,
             records[i] = _rest_out_to_record(cells[i], out, strict)
             if led is not None and isinstance(records[i], RunRecord):
                 _save_rest_shard(led, i, records[i])
-    if coop is not None:
-        coop.keeper.stop()
-        coop.keeper.join(timeout=5.0)
-        merged = (dict(getattr(_TLS, "batched_perf", None) or {})
-                  if batched_ran else {})
-        merged.update(coop.stats)
-        merged.update({k: float(v)
-                       for k, v in coop.keeper.stats().items()})
-        _TLS.batched_perf = merged
     if led is not None:
         failed = [r for r in records if isinstance(r, FailedCell)]
         status = ("truncated" if any(f.truncated for f in failed)
@@ -1256,53 +1117,6 @@ def _save_rest_shard(led, i: int, rec: RunRecord) -> None:
         pass               # best-effort, like the chunk shards
 
 
-def _run_rest_coop(cells, rest, records, led, coop, deadline,
-                   strict: bool) -> List[int]:
-    """Cooperative (lease-based) execution of the scalar-path cells:
-    claim ``cell:<i>`` leases, run, shard, release; cells leased to
-    other live workers are polled until their shard lands or their
-    lease expires. Returns the cell indices left unfinished (deadline
-    passed) — the caller truncates them."""
-    runner = _run_cell if strict else _run_cell_safe
-    waiting = list(rest)
-    while waiting:
-        if deadline is not None and time.monotonic() >= deadline:
-            return waiting
-        progressed = False
-        still = []
-        for i in waiting:
-            key = _ledger.chunk_key([f"cell:{i}"])
-            rec = _rest_shard_to_record(led.load_chunk(key))
-            if rec is not None:
-                records[i] = rec
-                progressed = True
-                continue
-            lease = led.claim_lease(key, coop.worker, coop.ttl)
-            if lease is None:
-                coop.stats["lease_conflicts"] += 1
-                still.append(i)
-                continue
-            coop.stats["lease_claims"] += 1
-            if lease.get("takeover_of"):
-                coop.stats["lease_takeovers"] += 1
-            faults.fire("worker.exit", key=_cell_fault_key(cells[i]))
-            coop.keeper.add(key, lease)
-            try:
-                out = runner(cells[i])
-            finally:
-                coop.keeper.remove(key)
-            records[i] = _rest_out_to_record(cells[i], out, strict)
-            if isinstance(records[i], RunRecord):
-                _save_rest_shard(led, i, records[i])
-            led.release_lease(key, lease)
-            progressed = True
-        waiting = still
-        if waiting and not progressed:
-            coop.stats["lease_wait_s"] += coop.poll_s
-            time.sleep(coop.poll_s)
-    return []
-
-
 def _rest_shard_to_record(items) -> Optional[RunRecord]:
     if not items:
         return None
@@ -1322,10 +1136,10 @@ def default_processes() -> int:
 # ------------------------------------------------------------ persistence
 def grid_to_doc(grid: ExperimentGrid) -> dict:
     """Full, *reconstructible* grid serialization, stored in run
-    manifests so a ``python -m repro.runs work`` worker can rebuild the
-    grid from the ledger alone (contrast :func:`_grid_meta`, a
-    human-oriented summary). Round-trips through
-    :func:`grid_from_doc` preserving ``grid_hash``."""
+    manifests so the grid can be rebuilt from the ledger alone
+    (contrast :func:`_grid_meta`, a human-oriented summary).
+    Round-trips through :func:`grid_from_doc` preserving
+    ``grid_hash``."""
     def cfg_doc(cfg: Optional[SimConfig]):
         return dataclasses.asdict(cfg) if cfg is not None else None
     return {

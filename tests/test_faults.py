@@ -140,11 +140,10 @@ def test_env_plan_installed_at_import():
                    cwd=os.path.dirname(os.path.dirname(__file__)))
 
 
-# --------------------------------------------- lease / serving sites
+# --------------------------------------------- ledger / serving sites
 
 def test_distributed_and_serving_sites_registered():
-    for site in ("lease.claim", "lease.heartbeat", "chunk.resplit",
-                 "worker.exit", "serve.admit", "serve.preempt",
+    for site in ("chunk.resplit", "serve.admit", "serve.preempt",
                  "serve.page_alloc"):
         assert site in faults.SITES
         # every site name parses in a clause
@@ -152,16 +151,15 @@ def test_distributed_and_serving_sites_registered():
 
 
 def test_lease_sites_fire_through_ledger(tmp_path, monkeypatch):
+    """``records.save`` fires in ``RunLedger.save_chunk`` before the
+    shard is written: a raised save leaves no shard behind."""
     monkeypatch.setenv("REPRO_RUNS_DIR", str(tmp_path / "runs"))
     from repro.core.ledger import RunLedger
     led = RunLedger("f1")
     led.open({"grid_hash": "h"})
-    with faults.injected("lease.claim@1=raise"):
+    with faults.injected("records.save[chunk:k]@1=raise"):
         with pytest.raises(InjectedFault):
-            led.claim_lease("k", "w", ttl=30.0)
-    doc = led.claim_lease("k", "w", ttl=30.0)
-    assert doc is not None
-    with faults.injected("lease.heartbeat@1=raise"):
-        with pytest.raises(InjectedFault):
-            led.heartbeat_lease("k", doc)
-    assert led.heartbeat_lease("k", doc) is True
+            led.save_chunk("k", [{"i": 0}])
+    assert led.load_chunk("k") is None
+    led.save_chunk("k", [{"i": 0}])
+    assert led.load_chunk("k") == [{"i": 0}]

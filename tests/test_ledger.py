@@ -1,15 +1,12 @@
 """Run ledger (repro.core.ledger): checkpoint shards, resume semantics,
-the chunk-lease protocol for cooperating workers, and the central
-property — a run interrupted after any prefix of chunks (or a worker
-SIGKILLed while holding a lease) still reassembles records
-**bit-identical** to an uninterrupted serial run, re-executing only the
-incomplete chunks."""
+and the central property — a run interrupted after any prefix of chunks
+(or SIGKILLed mid-chunk) still reassembles records **bit-identical** to
+an uninterrupted serial run, re-executing only the incomplete chunks."""
 import json
 import os
 import signal
 import subprocess
 import sys
-import threading
 import time
 
 import pytest
@@ -135,7 +132,7 @@ def test_process_engine_cells_get_per_cell_shards():
     assert recs == base
 
 
-# ------------------------------------------------------ lease protocol
+# ------------------------------------------------------------ grid doc
 
 def test_grid_doc_round_trips_grid_hash():
     doc = grid_to_doc(GRID)
@@ -143,84 +140,6 @@ def test_grid_doc_round_trips_grid_hash():
     # docs are plain JSON: survive a serialization round trip too
     assert grid_hash(grid_from_doc(json.loads(json.dumps(doc)))) \
         == grid_hash(GRID)
-
-
-def test_lease_lifecycle_claim_heartbeat_release():
-    led = RunLedger("life")
-    led.open({"grid_hash": "h"})
-    doc = led.claim_lease("k", "w1", ttl=30.0)
-    assert doc is not None and doc["takeover_of"] is None
-    assert led.claim_lease("k", "w2", ttl=30.0) is None   # live elsewhere
-    assert led.heartbeat_lease("k", doc) is True
-    led.release_lease("k", doc)
-    assert led.read_lease("k") is None
-    doc2 = led.claim_lease("k", "w2", ttl=30.0)
-    assert doc2 is not None and doc2["takeover_of"] is None
-
-
-def test_expired_lease_taken_over_stale_heartbeat_rejected():
-    led = RunLedger("exp")
-    led.open({"grid_hash": "h"})
-    doc = led.claim_lease("k", "w1", ttl=0.05)
-    assert doc is not None
-    time.sleep(0.12)
-    assert led.leases()[0]["expired"]
-    took = led.claim_lease("k", "w2", ttl=30.0)
-    assert took is not None and took["takeover_of"] == "w1"
-    # the original holder is fenced out: heartbeat and release both
-    # see a foreign nonce and back off without touching the new lease
-    assert led.heartbeat_lease("k", doc) is False
-    led.release_lease("k", doc)
-    assert led.read_lease("k")["worker"] == "w2"
-
-
-def test_racing_claims_exactly_one_winner():
-    """The unit-level mutual-exclusion guarantee: N threads claiming the
-    same chunk at the same instant — exactly one gets the lease, every
-    loser gets None and backs off."""
-    led = RunLedger("race")
-    led.open({"grid_hash": "h"})
-    for rnd in range(6):
-        key, nthreads = f"c{rnd}", 4
-        barrier = threading.Barrier(nthreads)
-        results = {}
-
-        def claim(w):
-            barrier.wait()
-            results[w] = led.claim_lease(key, w, ttl=30.0)
-
-        threads = [threading.Thread(target=claim, args=(f"w{k}",))
-                   for k in range(nthreads)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        winners = [w for w, doc in results.items() if doc is not None]
-        assert len(winners) == 1, (key, winners)
-        loser = next(w for w in results if w not in winners)
-        assert led.claim_lease(key, loser, ttl=30.0) is None
-
-
-def test_worker_exit_fault_leaves_lease_then_takeover():
-    """A worker that dies right after claiming (the ``worker.exit``
-    site) leaves its lease behind; a later worker takes it over once
-    the TTL lapses and finishes the run bit-identically."""
-    base = _base()
-    with faults.injected("worker.exit@1=raise"):
-        with pytest.raises(InjectedFault):
-            run_grid(GRID, engine="batched", run_id="wx",
-                     coordinate=True, lease_ttl_s=0.2, worker="w1")
-    led = RunLedger("wx")
-    leases = led.leases()
-    assert leases and leases[0]["worker"] == "w1"
-    time.sleep(0.25)                       # let the abandoned lease expire
-    recs = run_grid(GRID, engine="batched", resume="wx",
-                    coordinate=True, lease_ttl_s=0.2, worker="rescuer")
-    assert recs == base
-    perf = last_batched_perf()
-    assert perf["lease_takeovers"] >= 1
-    assert perf["lease_claims"] >= 1
-    assert json.loads(led.manifest_path.read_text())["status"] == "complete"
 
 
 # -------------------------------------------- interrupt → resume property
@@ -235,15 +154,15 @@ def _prop_base(backend, jobs):
     return _PROP_BASE[backend, jobs]
 
 
-@settings(max_examples=6, deadline=None)
-@given(st.integers(min_value=1, max_value=4),
-       st.sampled_from(BACKENDS),
-       st.sampled_from([1, 2]))
-def test_interrupted_run_resumes_bit_identical(kill_after, backend, jobs):
+@pytest.mark.parametrize("jobs", [1, 2])
+@pytest.mark.parametrize("backend", BACKENDS)
+@settings(max_examples=2, deadline=None)
+@given(st.integers(min_value=1, max_value=4))
+def test_interrupted_run_resumes_bit_identical(backend, jobs, kill_after):
     """Kill a strict run after ``kill_after`` chunk dispatches, resume
     from its ledger: only incomplete chunks re-run, and the final
-    records equal the uninterrupted run's bit for bit — across both
-    steppers and worker counts, over a limit-sweep grid.
+    records equal the uninterrupted run's bit for bit — on each stepper
+    and worker count, over a limit-sweep grid.
 
     Environment handling is manual (no monkeypatch): function-scoped
     fixtures don't reset between hypothesis examples."""
@@ -274,102 +193,53 @@ def test_interrupted_run_resumes_bit_identical(kill_after, backend, jobs):
                 os.environ[k] = v
 
 
-# ------------------------------- cooperating worker processes (SIGKILL)
+# ------------------------------------------- a real process death (SIGKILL)
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# the victim: one strict ledgered run of the grid given as a JSON doc
+_VICTIM = """
+import json, sys
+from repro.core.runner import grid_from_doc, run_grid
+run_grid(grid_from_doc(json.loads(sys.argv[2])), engine="batched",
+         strict=True, run_id=sys.argv[1])
+"""
 
-def _spawn_worker(run_id, wid, fault_plan=None):
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_sigkilled_run_resumes_bit_identical(backend, monkeypatch):
+    """A real process dies mid-run: the subprocess writes its first
+    shard, stalls inside its second chunk dispatch, and is SIGKILLed
+    there. Its manifest still says ``running``; an in-process resume
+    re-runs only what is missing and equals the serial run bit for
+    bit."""
+    # small token budget => several chunks, so the kill lands mid-run
+    monkeypatch.setenv("REPRO_BATCH_TOKEN_BUDGET", "60000")
+    monkeypatch.setenv("REPRO_BATCHED_BACKEND", backend)
+    base = run_grid(GRID, engine="batched")
+    run_id = f"kill-{backend}"
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(_REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
-    env["REPRO_WORKER_ID"] = wid
-    env.pop("REPRO_FAULT_PLAN", None)
-    if fault_plan:
-        env["REPRO_FAULT_PLAN"] = fault_plan
-    return subprocess.Popen(
-        [sys.executable, "-m", "repro.runs", "work", run_id,
-         "--engine", "batched", "--lease-ttl", "1"],
-        cwd=_REPO, env=env, text=True,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-
-
-_MW_BASE = {}     # backend -> serial records
-
-
-def _mw_base(backend):
-    if backend not in _MW_BASE:
-        _MW_BASE[backend] = run_grid(GRID, engine="batched")
-    return _MW_BASE[backend]
-
-
-@settings(max_examples=2, deadline=None)
-@given(st.integers(min_value=2, max_value=3))
-def test_multiworker_sigkill_survivors_bit_identical(nworkers):
-    """The tentpole property, with real processes: 2–3 workers drain
-    one run; the first is SIGKILLed while stalled inside its first
-    chunk (holding the lease). Survivors take the lease over and
-    finish, and the reassembled records equal a serial run bit for bit
-    — on both steppers (looped inside the example: the hypothesis stub
-    can't compose with parametrize). Environment handling is manual
-    (no monkeypatch): function-scoped fixtures don't reset between
-    hypothesis examples."""
-    for backend in BACKENDS:
-        _multiworker_scenario(backend, nworkers)
-
-
-def _multiworker_scenario(backend, nworkers):
-    import tempfile
-    saved = {k: os.environ.get(k)
-             for k in ("REPRO_RUNS_DIR", "REPRO_BATCHED_BACKEND",
-                       "REPRO_BATCH_TOKEN_BUDGET")}
-    os.environ["REPRO_RUNS_DIR"] = tempfile.mkdtemp(prefix="repro-mw-")
-    os.environ["REPRO_BATCHED_BACKEND"] = backend
-    # small token budget => several chunks, so there is work to steal
-    os.environ["REPRO_BATCH_TOKEN_BUDGET"] = "60000"
-    procs = []
+    env["REPRO_FAULT_PLAN"] = "chunk.dispatch@2=delay:60"
+    victim = subprocess.Popen([sys.executable, "-c", _VICTIM, run_id,
+                               json.dumps(grid_to_doc(GRID))],
+                              cwd=_REPO, env=env,
+                              stdout=subprocess.DEVNULL,
+                              stderr=subprocess.DEVNULL)
+    led = RunLedger(run_id)
     try:
-        base = _mw_base(backend)
-        run_id = f"mw-{backend}-{nworkers}"
-        led = RunLedger(run_id)
-        led.open({"grid_hash": grid_hash(GRID),
-                  "grid_doc": grid_to_doc(GRID),
-                  "engine": "batched", "cells": len(base)},
-                 status="pending")
-        # the victim stalls for 60s inside its first chunk dispatch --
-        # exactly the window in which we SIGKILL it, mid-lease
-        victim = _spawn_worker(run_id, "victim",
-                               fault_plan="chunk.dispatch@1=delay:60")
-        procs.append(victim)
         t0 = time.time()
-        while time.time() - t0 < 60.0 and not led.leases():
+        while time.time() - t0 < 60.0 and not led.completed_keys():
+            assert victim.poll() is None, "victim exited before a shard"
             time.sleep(0.05)
-        assert led.leases(), "victim never claimed a chunk"
+        assert led.completed_keys(), "victim never wrote a shard"
+    finally:
         os.kill(victim.pid, signal.SIGKILL)
         victim.wait(timeout=60)
-        survivors = [_spawn_worker(run_id, f"s{k}")
-                     for k in range(nworkers - 1)]
-        procs.extend(survivors)
-        for p in survivors:
-            out, _ = p.communicate(timeout=300)
-            assert p.returncode == 0, out
-        takeovers = sum(int(d.get("lease_takeovers", 0) or 0)
-                        for d in led.worker_summaries())
-        assert takeovers >= 1, led.worker_summaries()
-        assert json.loads(
-            led.manifest_path.read_text())["status"] == "complete"
-        # reassembly re-executes nothing and equals the serial run
-        recs = run_grid(GRID, engine="batched", resume=run_id)
-        assert recs == base
-        perf = last_batched_perf()
-        assert perf["chunks_resumed"] == perf["chunks"]
-        assert perf["stepper_s"] == 0.0
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+    assert led.load()["status"] == "running"
+    recs = run_grid(GRID, engine="batched", resume=run_id)
+    assert recs == base
+    perf = last_batched_perf()
+    assert 1 <= perf["chunks_resumed"] < perf["chunks"]
+    assert led.load()["status"] == "complete"

@@ -76,6 +76,8 @@ def test_poisoned_cell_quarantined_siblings_survive():
             "cell.run[syrk/ciao-c]@*=raise")
     with faults.injected(plan):
         recs = run_grid(GRID, engine="batched", retries=1)
+    # read before _base(), whose first call runs (and records) a grid
+    assert last_batched_perf()["failed_cells"] == 1
     failed = [r for r in recs if isinstance(r, FailedCell)]
     assert len(failed) == 1
     f = failed[0]
@@ -89,7 +91,6 @@ def test_poisoned_cell_quarantined_siblings_survive():
     base = {(r.workload, r.policy): r for r in _base()}
     for key, rec in ok.items():
         assert rec == base[key]
-    assert last_batched_perf()["failed_cells"] == 1
 
 
 def test_failed_cell_json_round_trip(tmp_path):
@@ -118,9 +119,10 @@ def test_limit_sweep_survives_poisoned_subcell():
 def test_deadline_never_fires_is_bit_identical():
     """Arming a (generous) deadline switches single-SM batches to
     bounded-cycle slicing; the records must not change."""
+    base = _base()
     recs = run_grid(GRID, engine="batched", deadline_s=600.0)
-    assert recs == _base()
     assert last_batched_perf()["truncated_cells"] == 0
+    assert recs == base
 
 
 def test_deadline_mid_run_truncates_resumably(monkeypatch):
@@ -203,8 +205,9 @@ def test_chunk_budget_without_ledger_still_completes(monkeypatch):
 
 def test_crash_at_resplit_publication_is_resumable(monkeypatch):
     """Dying between the budget blowout and the resplit record landing
-    (the ``chunk.resplit`` site) loses nothing: the next worker re-runs
+    (the ``chunk.resplit`` site) loses nothing: the resumed run re-runs
     or re-splits the parent chunk and records stay identical."""
+    base = _base()
     _tiny_slices(monkeypatch)
     plan = "stepper.step@*=delay:0.02,chunk.resplit@1=raise"
     with faults.injected(plan):
@@ -212,13 +215,13 @@ def test_crash_at_resplit_publication_is_resumable(monkeypatch):
             run_grid(GRID, engine="batched", run_id="rs2",
                      chunk_budget_s=0.01, strict=True)
     recs = run_grid(GRID, engine="batched", resume="rs2")
-    assert recs == _base()
     assert last_batched_perf()["failed_cells"] == 0
+    assert recs == base
 
 
 def test_resplit_crash_publishes_nothing(monkeypatch):
     """The ``chunk.resplit`` site fires *before* the record lands: a
-    crash there leaves no resplit doc behind, and the next worker
+    crash there leaves no resplit doc behind, and the resumed run
     simply re-runs (or re-splits) the whole parent chunk."""
     from repro.core.ledger import RunLedger
     _tiny_slices(monkeypatch)

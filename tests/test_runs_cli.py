@@ -1,7 +1,7 @@
-"""Run lifecycle CLI (python -m repro.runs): create/work/show/list/gc,
-orphaned-run repair, and the CI assertion flags. Everything goes
-through ``runs.main(argv)`` in-process — the same entrypoint the chaos
-smoke drives as a subprocess."""
+"""Run lifecycle CLI (python -m repro.runs): show/list/gc over ledgers
+that ``run_grid(run_id=...)`` wrote, orphaned-run repair, and the CI
+assertion flag. Everything goes through ``runs.main(argv)``
+in-process."""
 import json
 import os
 import time
@@ -11,11 +11,9 @@ import pytest
 from repro import runs as runs_cli
 from repro.core import faults
 from repro.core.ledger import RunLedger, grid_hash, runs_root
-from repro.core.runner import (ExperimentGrid, grid_from_doc,
+from repro.core.runner import (ExperimentGrid, expand_grid,
                                last_batched_perf, run_grid)
 
-GRID_ARGS = ["--workloads", "syrk,kmn", "--policies", "gto,ciao-c",
-             "--scale", "0.05", "--engine", "batched", "--name", "cli"]
 GRID = ExperimentGrid(name="cli", workloads=("syrk", "kmn"),
                       policies=("gto", "ciao-c"), scale=0.05)
 
@@ -33,67 +31,45 @@ def _backdate(led, seconds):
     """Age every ledger file so staleness/gc probes see an idle run."""
     old = time.time() - seconds
     paths = [led.manifest_path]
-    for sub in (led.chunk_dir, led.lease_dir, led.resplit_dir,
-                led.worker_dir):
+    for sub in (led.chunk_dir, led.resplit_dir):
         if sub.is_dir():
             paths.extend(sub.glob("*.json"))
     for p in paths:
         os.utime(p, (old, old))
 
 
-# ------------------------------------------------------- create + work
+def _pending(run_id, status="pending"):
+    """A ledger opened for GRID with nothing run yet."""
+    led = RunLedger(run_id)
+    led.open({"grid_hash": grid_hash(GRID), "engine": "batched",
+              "cells": len(expand_grid(GRID))}, status=status)
+    return led
+
+
+# ---------------------------------------------------------------- show
 
 def test_create_work_show_roundtrip(capsys):
-    assert runs_cli.main(["create", "run1"] + GRID_ARGS) == 0
-    led = RunLedger("run1")
-    assert led.load()["status"] == "pending"
-    # the stored grid_doc reconstructs the exact grid (hash round trip)
-    grid = grid_from_doc(led.manifest["grid_doc"])
-    assert grid_hash(grid) == led.manifest["grid_hash"]
-    assert runs_cli.main(["work", "run1", "--worker", "w1"]) == 0
-    assert led.load()["status"] == "complete"
-    out = capsys.readouterr().out
-    assert "# worker w1: complete" in out
+    base = run_grid(GRID, engine="batched")
+    assert run_grid(GRID, engine="batched", run_id="run1") == base
     assert runs_cli.main(["show", "run1",
                           "--assert-status", "complete"]) == 0
     assert runs_cli.main(["show", "run1",
                           "--assert-status", "running"]) == 1
-    # the drained run's records equal an ordinary serial run
-    base = run_grid(GRID, engine="batched")
+    # the ledgered run resumes to the serial records, re-running nothing
     recs = run_grid(GRID, engine="batched", resume="run1")
     assert recs == base
     assert last_batched_perf()["stepper_s"] == 0.0
 
 
-def test_create_existing_requires_force():
-    assert runs_cli.main(["create", "dup"] + GRID_ARGS) == 0
-    assert runs_cli.main(["create", "dup"] + GRID_ARGS) == 1
-    assert runs_cli.main(["create", "dup", "--force"] + GRID_ARGS) == 0
-
-
 def test_work_missing_run_errors(capsys):
-    assert runs_cli.main(["work", "nope"]) == 1
+    assert runs_cli.main(["show", "nope"]) == 1
     assert "no readable manifest" in capsys.readouterr().err
-
-
-def test_work_records_worker_summary(capsys):
-    runs_cli.main(["create", "sum1"] + GRID_ARGS)
-    assert runs_cli.main(["work", "sum1", "--worker", "alpha"]) == 0
-    docs = RunLedger("sum1").worker_summaries()
-    assert [d["worker"] for d in docs] == ["alpha"]
-    assert docs[0]["status"] == "complete"
-    assert docs[0]["lease_claims"] >= 1
-    capsys.readouterr()
-    assert runs_cli.main(["show", "sum1", "--json"]) == 0
-    info = json.loads(capsys.readouterr().out)
-    assert info["workers"] == 1
-    assert info["worker_summaries"][0]["worker"] == "alpha"
 
 
 # ----------------------------------------------------------- list + gc
 
 def test_list_shows_runs(capsys):
-    runs_cli.main(["create", "l1"] + GRID_ARGS)
+    _pending("l1")
     capsys.readouterr()
     runs_cli.main(["list", "--json"])
     infos = json.loads(capsys.readouterr().out)
@@ -103,8 +79,8 @@ def test_list_shows_runs(capsys):
 
 
 def test_gc_age_based_retention(capsys):
-    runs_cli.main(["create", "old"] + GRID_ARGS)
-    runs_cli.main(["create", "new"] + GRID_ARGS)
+    _pending("old")
+    _pending("new")
     _backdate(RunLedger("old"), 3 * 86400)
     # dry run removes nothing
     assert runs_cli.main(["gc", "--older-than", "1d", "--dry-run"]) == 0
@@ -115,18 +91,12 @@ def test_gc_age_based_retention(capsys):
 
 
 def test_gc_protects_live_runs_without_force(capsys):
-    runs_cli.main(["create", "live"] + GRID_ARGS)
-    led = RunLedger("live")
-    led.load()
-    led.manifest["status"] = "running"
-    led._write_manifest()
-    doc = led.claim_lease("c1", "w1", ttl=10_000.0)   # live heartbeat
-    assert doc is not None
+    led = _pending("live", status="running")
     _backdate(led, 3 * 86400)
-    # the lease was backdated too -- refresh it so the run looks alive
-    led.heartbeat_lease("c1", doc)
-    assert runs_cli.main(["gc", "--older-than", "1d"]) == 0
+    led.save_chunk("c1", [])            # a fresh shard: the run is alive
+    assert runs_cli.main(["gc", "--older-than", "0s"]) == 0
     assert (runs_root() / "live").exists()
+    assert "still running" in capsys.readouterr().out
     assert runs_cli.main(["gc", "--older-than", "0s", "--force"]) == 0
     assert not (runs_root() / "live").exists()
 
@@ -142,13 +112,9 @@ def test_parse_age_grammar():
 # -------------------------------------------------------- orphan repair
 
 def _orphan(run_id):
-    """A run whose worker died without finish(): status still
-    'running', no live leases, files long silent."""
-    runs_cli.main(["create", run_id] + GRID_ARGS)
-    led = RunLedger(run_id)
-    led.load()
-    led.manifest["status"] = "running"
-    led._write_manifest()
+    """A run whose process died without finish(): status still
+    'running', files long silent."""
+    led = _pending(run_id, status="running")
     _backdate(led, 7200)
     return led
 
@@ -183,21 +149,16 @@ def test_resume_of_orphan_counts_interruption(monkeypatch):
     led.manifest["status"] = "running"
     led._write_manifest()
     _backdate(led, 7200)
-    monkeypatch.setenv("REPRO_LEASE_TTL", "30")     # stale_after >= 600 still
     recs = run_grid(GRID, engine="batched", resume="orph3")
     assert recs == base
     assert led.load()["interruptions"] == 1
     assert led.load()["status"] == "complete"
 
 
-def test_heartbeating_run_is_not_stale():
-    runs_cli.main(["create", "hb"] + GRID_ARGS)
-    led = RunLedger("hb")
-    led.load()
-    led.manifest["status"] = "running"
-    led._write_manifest()
+def test_recently_written_run_is_not_stale():
+    led = _pending("fresh", status="running")
     _backdate(led, 7200)
-    doc = led.claim_lease("c1", "w1", ttl=600.0)    # fresh heartbeat
-    assert doc is not None
+    led.save_chunk("c1", [])            # a freshly written shard
     assert led.probe_status(stale_after=600.0) == "running"
-    led.release_lease("c1", doc)
+    _backdate(led, 7200)                # ...and the same run gone silent
+    assert led.probe_status(stale_after=600.0) == "interrupted"
