@@ -59,8 +59,10 @@ SCOPES = (
     "ssd",         # ln1, the SSD mixer and its residual
     "head",        # the final norm and the logits (and the loss)
     "moe_route",   # in mlp: the router, its top-k and their weights
-    "moe_experts", # in mlp: the routed experts' matmuls (and dispatch)
+    "moe_experts", # in mlp: the routed experts' matmuls (and dispatch), or
+                   # the held_experts kernel, the shared expert folded in
     "moe_shared",  # in mlp: the shared expert (the MLP beside the experts)
+                   # where no kernel folds it in
     "ssd_state",   # in ssd: the state recurrence and its write into the
                    # stacked cache (decode), or the chunked scan (prefill)
 )
@@ -257,15 +259,28 @@ def _attn_tail(env, cfg, bp, x, h, *, positions, chunk, enc_out,
     return _ffn_part(env, cfg, bp, x, serving=serving)[0], cross
 
 
-def _ffn_part(env, cfg, bp, x, serving=False):
+def _ffn_part(env, cfg, bp, x, serving=False, stacked=None, layer=None):
     """ln2, the FFN and its residual. Returns (x, counts). An expert layer
     drops tokens past capacity in training; on the serving path
     (``serving``) it drops none and counts the routed assignments that land
-    on each expert it holds (``moe.moe_serve``); elsewhere counts is None."""
+    on each expert it holds (``moe.moe_serve``); elsewhere counts is None.
+    Where ``moe.streams_held_experts`` holds, the serving path's experts,
+    and the shared expert with them, are one ``held_experts`` kernel; given
+    ``stacked``, the block's weights stacked over the layers, it reads
+    them in place at ``layer``."""
     counts = None
     with jax.named_scope("mlp"):
         h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
-        if cfg.num_experts:
+        if not cfg.num_experts:
+            f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
+        elif serving and moe_mod.streams_held_experts(
+                env.mesh.size, env.mesh.devices.flat[0].platform,
+                h2.shape[0] * h2.shape[1]):
+            src, at = (bp, None) if stacked is None else (stacked, layer)
+            f, counts = moe_mod.moe_serve_streamed(
+                cfg, src["moe"], h2,
+                src["mlp"] if cfg.moe_dense_residual else None, layer=at)
+        else:
             if serving:
                 f, counts = moe_mod.moe_serve(cfg, bp["moe"], h2)
             else:
@@ -274,8 +289,6 @@ def _ffn_part(env, cfg, bp, x, serving=False):
                 with jax.named_scope("moe_shared"):
                     f = f + L.mlp_apply(env, bp["mlp"], h2,
                                         cfg.mlp_activation)
-        else:
-            f = L.mlp_apply(env, bp["mlp"], h2, cfg.mlp_activation)
         return _residual(cfg, x, f), counts
 
 
@@ -347,7 +360,7 @@ def apply_block_prefill(env, cfg, kind, bp, x, cache_entry, *, positions,
 
 
 def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
-                       layer=None):
+                       layer=None, stacked=None):
     """One-token step. x_t: (B, 1, d); pos: (B,) absolute position.
 
     With ``layer``, the leaves of ``cache_entry`` are stacked over the
@@ -355,9 +368,11 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
     value are written in place at (layer, slot, position) and, on one TPU
     chip, a full-length cache's self-attention reads its layer there
     (``_in_place_attention``); the recurrent state is rewritten at
-    ``layer`` (an SSD's under ``ssd_state``). Returns (x_t, new_entry,
-    counts): an expert layer's counts of the serving path (``_ffn_part``),
-    else None."""
+    ``layer`` (an SSD's under ``ssd_state``); and ``stacked``, the
+    block's weights stacked over the layers, is where the held experts'
+    kernel reads its layer's weights (``_ffn_part``). Returns (x_t,
+    new_entry, counts): an expert layer's counts of the serving path
+    (``_ffn_part``), else None."""
     if kind in ATTN_BLOCKS:
         h, q, k, v = _qkv(env, cfg, bp, x_t, pos[:, None])
         ring = kind == BLOCK_LOCAL_ATTN
@@ -394,7 +409,8 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
                                         ring=False, cross=True)
                 x_t = _residual(cfg, x_t,
                                 attn.output_proj(env, cfg, bp["cross"], co))
-        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True)
+        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True,
+                                stacked=stacked, layer=layer)
         return x_t, new, counts
     if kind not in (BLOCK_RGLRU, BLOCK_SSD):
         raise ValueError(kind)
@@ -420,7 +436,8 @@ def apply_block_decode(env, cfg, kind, bp, x_t, cache_entry, *, pos,
                 new = write(*new_state)
     counts = None
     if kind == BLOCK_RGLRU or cfg.ssd_ffn:
-        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True)
+        x_t, counts = _ffn_part(env, cfg, bp, x_t, serving=True,
+                                stacked=stacked, layer=layer)
     return x_t, new, counts
 
 
@@ -543,7 +560,8 @@ def _run_stack_prefill(env, cfg, params, x, cache, *, positions, prefix_len,
 def _run_stack_decode(env, cfg, params, x_t, cache, *, pos):
     """The decode step's layers. The stacked cache rides in the scan's carry
     beside ``x_t``, and each block writes its layer of it in place; the
-    scan's xs are the stacked weights and the layer index. Returns
+    scan's xs are the stacked weights and the layer index, and a kernel
+    that reads its layer's weights in place takes them stacked. Returns
     (x_t, cache, counts): counts, for a model with experts, the routed
     assignments on each held expert of each layer, (num_layers, held)
     int32, else None."""
@@ -559,7 +577,7 @@ def _run_stack_decode(env, cfg, params, x_t, cache, *, pos):
         for i, kind in enumerate(cfg.pattern):
             x_t, stack[f"b{i}"], n = apply_block_decode(
                 env, cfg, kind, lp[f"b{i}"], x_t, stack[f"b{i}"], pos=pos,
-                layer=layer)
+                layer=layer, stacked=params["stack"][f"b{i}"])
             counts.append(n)
         return (x_t, stack), stacked(counts)
 

@@ -22,7 +22,9 @@ Tokens beyond an expert's capacity are dropped (GShard semantics) in
 training; tests use a high capacity factor and compare against the dense
 oracle. The serving path (``moe_serve``) drops none: it runs every held
 expert on every token and weights each output by the routing, as the dense
-oracle does.
+oracle does. On one TPU chip and a few rows (``streams_held_experts``) the
+``held_experts`` kernel computes it, reading each expert weight once
+(``moe_serve_streamed``).
 
 A config with ``moe_held`` holds a share of the experts (a chip's under
 expert parallelism): the router keeps all ``num_experts`` outputs and picks
@@ -39,6 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
+from repro.kernels.held_experts.ops import held_experts
 from repro.models.layers import GATED, mlp_activate, nd_init
 
 BIG = jnp.iinfo(jnp.int32).max
@@ -190,12 +193,11 @@ def moe_apply(env, cfg, params, x, *, capacity_factor: float = 2.0):
         return fn(x, ids, gate_w, params["w_in"], w_gate, params["w_out"])
 
 
-def moe_serve(cfg, params, x):
-    """The serving path: every held expert on every token, each expert's
-    activations weighted by its routing weight (0 where the token was not
-    routed to it) before the output projection, so no token is dropped.
-    Returns (out, counts): out (B, S, d), and counts (held,) int32, the
-    routed assignments that land on each held expert."""
+def _serve_route(cfg, params, x):
+    """The serving path's routing: each held expert's routing weight of
+    each token, (B, S, held) float32, 0 where the token was not routed to
+    it; and counts (held,) int32, the routed assignments that land on each
+    held expert."""
     held = cfg.moe_held or cfg.num_experts
     with jax.named_scope("moe_route"):
         gate_w, ids = _route(cfg, params, x)                   # (B,S,k)
@@ -203,6 +205,16 @@ def moe_serve(cfg, params, x):
                                 dtype=jnp.float32)             # (B,S,k,E)
         w = jnp.einsum("bske,bsk->bse", routed, gate_w)
         counts = routed.sum((0, 1, 2)).astype(jnp.int32)
+    return w, counts
+
+
+def moe_serve(cfg, params, x):
+    """The serving path: every held expert on every token, each expert's
+    activations weighted by its routing weight (0 where the token was not
+    routed to it) before the output projection, so no token is dropped.
+    Returns (out, counts): out (B, S, d), and counts (held,) int32, the
+    routed assignments that land on each held expert."""
+    w, counts = _serve_route(cfg, params, x)
     with jax.named_scope("moe_experts"):
         h = jnp.einsum("bsd,edf->bsef", x, params["w_in"],
                        preferred_element_type=jnp.float32)
@@ -214,6 +226,43 @@ def moe_serve(cfg, params, x):
         out = jnp.einsum("bsef,efd->bsd", h, params["w_out"],
                          preferred_element_type=jnp.float32)
     return out.astype(x.dtype), counts
+
+
+# v5e's ridge point in rows: its bf16 peak (197 TFLOP/s) over its HBM
+# bandwidth (819 GB/s). Evaluating every held expert on R rows takes 2R
+# operations for each 2-byte weight, so at or below this many rows the
+# layer is bound by its weights' bytes.
+RIDGE_ROWS = int(197e12 / 819e9)
+
+
+def streams_held_experts(mesh_size: int, platform: str, rows: int) -> bool:
+    """Whether the serving path runs its held experts through the
+    ``held_experts`` kernel (``moe_serve_streamed``): on one TPU chip (a
+    custom call is not partitioned over a mesh), for at most
+    ``RIDGE_ROWS`` rows, where streaming the weights bounds the layer.
+    Above them the layer is bound by its operations, and XLA's einsums
+    (``moe_serve``) keep it."""
+    return mesh_size == 1 and platform == "tpu" and rows <= RIDGE_ROWS
+
+
+def moe_serve_streamed(cfg, params, x, shared=None, *, layer=None):
+    """``moe_serve`` through the ``held_experts`` kernel, which reads each
+    held expert's weights once; with ``shared``, the shared expert's MLP
+    weights, the layer's output also holds the shared expert's, folded
+    into the kernel as one more expert of weight 1 and accumulated in
+    float32. With ``layer``, ``params`` and ``shared`` are stacked over the
+    layers and the kernel reads its layer's in place. Returns (out, counts)
+    as ``moe_serve`` does."""
+    route = params
+    if layer is not None:
+        route = {"router": jax.lax.dynamic_index_in_dim(
+            params["router"], layer, 0, keepdims=False)}
+    w, counts = _serve_route(cfg, route, x)
+    with jax.named_scope("moe_experts"):
+        out = held_experts(
+            x, w, params, shared, layer=layer,
+            activate=functools.partial(mlp_activate, cfg.mlp_activation))
+    return out, counts
 
 
 def moe_ref(cfg, params, x):

@@ -6,6 +6,7 @@ not fit), at no chip time. Nothing runs, so nothing here checks a value.
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
+import functools
 import json
 import os
 import pathlib
@@ -22,10 +23,15 @@ from repro.configs.base import RunConfig
 from repro.kernels.ciao_gather.ops import ciao_gather
 from repro.kernels.decode_attn.ops import decode_attention
 from repro.kernels.flash_attn.ops import flash_attention
+from repro.kernels.held_experts.ops import held_experts
 from repro.models import model as M
+from repro.models import moe as MOE
+from repro.models.layers import mlp_activate
 from repro.parallel.sharding import local_env
 
 HQ, HKV, D, CAP = 8, 4, 256, 50.0       # gemma2-2b attention widths
+# granite-4.0-h-small's expert layer on one chip: 18 held experts
+EXPERTS, D_MODEL, FF, SHARED_FF = 18, 4096, 768, 1536
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
@@ -86,6 +92,29 @@ def test_ciao_gather_compiles_for_v5e(one_chip, dtype):
     c = _compile(one_chip, ciao_gather,
                  ((262144, 256), dtype), ((65536,), jnp.int32),
                  ((65536,), jnp.int32), ((4,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_held_experts_compiles_for_v5e(one_chip, shared):
+    """The kernel at granite's widths on 96 rows, its weights stacked over
+    the layers: its double-buffered blocks fit the VMEM it asks for."""
+    def fn(x, w, w_in, w_gate, w_out, s_in, s_gate, s_out, layer):
+        common = dict(w_in=s_in, w_gate=s_gate, w_out=s_out)
+        return held_experts(x, w, dict(w_in=w_in, w_gate=w_gate,
+                                       w_out=w_out),
+                            common if shared else None, layer=layer,
+                            activate=functools.partial(mlp_activate,
+                                                       "swiglu"))
+    experts = (2, EXPERTS, D_MODEL, FF)
+    common = (2, D_MODEL, SHARED_FF)
+    c = _compile(one_chip, fn,
+                 ((96, 1, D_MODEL), jnp.bfloat16),
+                 ((96, 1, EXPERTS), jnp.float32),
+                 (experts, jnp.bfloat16), (experts, jnp.bfloat16),
+                 ((2, EXPERTS, FF, D_MODEL), jnp.bfloat16),
+                 (common, jnp.bfloat16), (common, jnp.bfloat16),
+                 ((2, SHARED_FF, D_MODEL), jnp.bfloat16), ((), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -216,3 +245,43 @@ def test_granite_stage_decode_and_longest_prefill_fit_v5e(one_chip):
         leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
     assert dec.argument_size_in_bytes + pre.temp_size_in_bytes \
         + pre.output_size_in_bytes < 14e9
+
+
+def test_granite_stage_decode_step_streams_held_experts_for_v5e(
+        one_chip, monkeypatch):
+    """granite-4.0-h-small's served stage at 96 slots of 4096, the cache
+    donated, with the kernels the model takes on one chip: each of the 10
+    layers' held experts (and its shared expert) are one ``held_experts``
+    call, no copy moves an expert weight (XLA's whole-tensor prefetches
+    into VMEM are gone), and the step's temporaries stay small."""
+    from chipbench.drivers import serve_hybrid
+    rule = MOE.streams_held_experts
+    monkeypatch.setattr(MOE, "streams_held_experts",
+                        lambda n, platform, rows: rule(n, "tpu", rows))
+    monkeypatch.setattr(M, "_in_place_attention", lambda env: decode_attention)
+    cfg = serve_hybrid.model_config(json.loads(
+        (ROOT / "chipbench/configs/granite-4.0-h-small-pp4ep4.json")
+        .read_text()))
+    run = RunConfig(remat_policy="none", param_dtype="bfloat16")
+    fns = serve_hybrid.programs(cfg, run, 4096, jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    c = fns["decode"].lower(
+        on_chip(M.param_shapes(cfg, run)),
+        jax.ShapeDtypeStruct((96, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((96,), jnp.int32, sharding=one_chip),
+        on_chip(M.cache_struct(cfg, 96, 4096))).compile()
+    text = c.as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%held_experts[.\d]* = .* custom-call\(",
+                       text, re.M)
+    assert len(calls) == cfg.num_layers == 10
+    expert = {f"{EXPERTS},{D_MODEL},{FF}", f"{EXPERTS},{FF},{D_MODEL}"}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[(?:1,)?([\d,]*)\].* "
+                     r"(copy|copy-start)\((%[^)]*)\)", line)
+        assert not (m and (m.group(1) in expert
+                           or "____moe____w_" in m.group(3))), line
+    assert c.memory_analysis().temp_size_in_bytes < 0.2e9

@@ -4,9 +4,12 @@ benchmark's seeded weights: prefill then decode through the cache gives
 the reference's logits; the four shares of the experts, with the shared
 expert counted once, give the uncut layer; a router that sends every token
 to one expert drops nothing on the serving path; the float8 control fails
-the tolerance; and bf16 routing departs from float32 routing only at near
-ties of the router's logits."""
+the tolerance; bf16 routing departs from float32 routing only at near
+ties of the router's logits; and the ``held_experts`` kernel gives the
+serving path's layer, with the shared expert folded in, at the few rows
+for which the model takes it."""
 import dataclasses
+import functools
 import pathlib
 import sys
 
@@ -22,6 +25,7 @@ if str(ROOT) not in sys.path:
 from chipbench import harness  # noqa: E402
 from chipbench.drivers import serve_hybrid as H  # noqa: E402
 from chipbench.reference import hybrid_lm  # noqa: E402
+from repro.configs import reduced_config  # noqa: E402
 from repro.configs.base import RunConfig  # noqa: E402
 from repro.models import layers as L  # noqa: E402
 from repro.models import model as M  # noqa: E402
@@ -188,3 +192,78 @@ def test_bf16_routing_departs_only_at_near_ties():
     assert 0 < flips.mean() < 0.05
     # the inputs' bf16 rounding moves a logit by about 1e-2 here
     assert margin[flips].max() < 0.05 < np.median(margin)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The ``held_experts`` kernel in interpret mode, as the CPU runs it."""
+    monkeypatch.setattr(MOE, "held_experts", functools.partial(
+        MOE.held_experts, interpret=True))
+
+
+@pytest.mark.parametrize("shared", [False, True])
+@pytest.mark.parametrize("rows", [1, 8, 13, 96])
+def test_held_experts_kernel_matches_moe_serve(rows, shared, interpreted):
+    """The kernel (interpreted) against ``moe_serve``, plus the shared
+    expert's ``mlp_apply`` where it is folded in, over 4 of 16 experts held
+    from 4: the first token is routed to no held expert, and the others
+    leave some held experts with no weight."""
+    cfg = _moe_case(4, 4)
+    params, _ = MOE.moe_init(_moe_case(), jax.random.PRNGKey(0), jnp.float32)
+    params["router"] = params["router"].at[0, 4:8].set(-100.0)
+    params = _held(params, 4, 4)
+    mlp, _ = L.mlp_init(jax.random.PRNGKey(1), 64, 64, "swiglu", jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(2), (rows, 1, 64))
+    x = x.at[:, :, 0].set(0.0).at[0, 0, 0].set(10.0)
+    w, _ = MOE._serve_route(cfg, params, x)
+    assert not bool(w[0].any()) and not bool(w.all())
+    want, want_counts = MOE.moe_serve(cfg, params, x)
+    if shared:
+        want = want + L.mlp_apply(ENV, mlp, x, "swiglu")
+    got, counts = MOE.moe_serve_streamed(cfg, params, x,
+                                         mlp if shared else None)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+    np.testing.assert_array_equal(counts, want_counts)
+
+
+def test_decode_step_through_the_kernel_reads_each_layer_in_place(
+        monkeypatch, interpreted):
+    """granite's reduced config holds two periods, so the decode scan runs
+    twice: with every expert layer through the kernel (interpreted), which
+    reads its layer of the stacked weights, the decode step gives the XLA
+    path's logits, cache and counts."""
+    from repro.configs.granite_4_0_h_small import CONFIG
+    cfg = reduced_config(CONFIG)
+    assert cfg.scan_repeats == 2
+    params = M.init_params(cfg, jax.random.PRNGKey(5), F32)
+    cache = M.init_cache(cfg, 3, 16, kv_dtype=jnp.float32)
+    token = jax.random.randint(jax.random.PRNGKey(6), (3, 1), 0,
+                               cfg.vocab_size)
+    pos = jnp.asarray([0, 4, 9])
+
+    def step():
+        return M.decode_step(ENV, cfg, params, token, pos, cache, F32,
+                             expert_counts=True)
+    want = step()
+    rule = MOE.streams_held_experts
+    monkeypatch.setattr(MOE, "streams_held_experts",
+                        lambda n, platform, rows: rule(n, "tpu", rows))
+    got = step()
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-5)
+    assert int(got[2].sum()) == cfg.num_layers * 3 * cfg.num_experts_per_tok
+
+
+@pytest.mark.parametrize("mesh_size,platform,rows,streams", [
+    (1, "tpu", 96, True),           # a decode step of 96 slots
+    (1, "tpu", MOE.RIDGE_ROWS, True),
+    (1, "tpu", 256, False),         # a prefill: bound by its operations
+    (4, "tpu", 96, False),          # a mesh: the custom call is not split
+    (1, "cpu", 96, False),
+])
+def test_held_experts_kernel_only_where_weight_bytes_bound_one_chip(
+        mesh_size, platform, rows, streams):
+    assert 200 < MOE.RIDGE_ROWS < 256
+    assert MOE.streams_held_experts(mesh_size, platform, rows) is streams

@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles (interpret=True), sweeping shapes and
 dtypes as required for each kernel."""
+import functools
 import types
 
 import jax
@@ -12,8 +13,11 @@ from repro.kernels.flash_attn.ref import attention_ref
 from repro.kernels.decode_attn.ops import decode_attention
 from repro.kernels.decode_attn.ref import decode_ref
 from repro.kernels.ciao_gather.ops import ciao_gather
+from repro.kernels.held_experts.ops import held_experts
+from repro.kernels.held_experts.ref import held_experts_ref
 from repro.kernels.ciao_gather.ref import cache_sim_ref, gather_ref
 from repro.models.attention import decode_attend
+from repro.models.layers import mlp_activate
 from repro.parallel.sharding import local_env
 
 
@@ -100,6 +104,47 @@ def test_decode_attention_reads_a_stacked_cache_in_place(hq, hkv, cap, dtype,
     clean_k, clean_v = (jnp.where(live, x, 0) for x in (k, v))
     ref = decode_attend(local_env(), cfg, q, clean_k, clean_v,
                         jnp.asarray(lens - 1), ring=False)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("activation", ["swiglu", "gelu"])
+@pytest.mark.parametrize("shared", [False, True])
+def test_held_experts_reads_stacked_weights_in_place(shared, activation,
+                                                     dtype, atol):
+    """Three held experts of ff 256 in blocks of 128, and a shared expert
+    of ff 384, on 20 rows (padded to 32): one layer of weights stacked over
+    three, read in place, against the oracle on that layer alone. Every
+    other layer holds NaN, so a finite, matching output shows that the
+    kernel reads its own layer alone."""
+    layers, layer, e, d, ff, sff, rows = 3, 1, 3, 128, 256, 384, 20
+    ks = iter(jax.random.split(jax.random.PRNGKey(4), 10))
+    names = (("w_in", "w_gate", "w_out") if activation == "swiglu"
+             else ("w_in", "w_out"))
+
+    def weights(shape_in, shape_out):
+        return {n: jax.random.normal(next(ks), shape_out if n == "w_out"
+                                     else shape_in, dtype) * 0.1
+                for n in names}
+    held = weights((e, d, ff), (e, ff, d))
+    common = weights((d, sff), (sff, d)) if shared else None
+
+    def stacked(p):
+        return None if p is None else {
+            n: jnp.full((layers,) + w.shape, jnp.nan, dtype).at[layer].set(w)
+            for n, w in p.items()}
+    x = jax.random.normal(next(ks), (rows, 1, d), dtype)
+    w = jax.random.uniform(next(ks), (rows, 1, e)) * (
+        jax.random.uniform(next(ks), (rows, 1, e)) < 0.6)
+    act = functools.partial(mlp_activate, activation)
+    out = held_experts(x, w, stacked(held), stacked(common),
+                       layer=jnp.int32(layer), activate=act, block_ff=128,
+                       interpret=True)
+    ref = held_experts_ref(x, w, held, common, activate=act)
+    assert out.shape == x.shape and out.dtype == dtype
     assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol)
